@@ -215,12 +215,8 @@ def compiled_sweep(ranks, big_ranks, min_wall_s, engine=None) -> list[dict]:
 
 
 def engine_rows(nranks: int, min_wall_s: float) -> list[dict]:
-    """numpy-vs-jax scan-engine comparison on the batched grid (skipped
-    when jax is not importable; DESIGN.md §2.5)."""
-    from repro.core.exanet.scan_engine import available_engines
-    if "jax" not in available_engines():
-        print("engine rows: jax not importable, skipping")
-        return []
+    """numpy-vs-jax scan-engine comparison on the batched grid
+    (DESIGN.md §2.5)."""
     p = scaled_params((nranks - 1) * DEFAULT.cores_per_mpsoc + 1)
     mpi = ExanetMPI(p, ranks_per_mpsoc=1)
     rows = []
@@ -295,6 +291,8 @@ def main(out_path: str = "BENCH_collectives.json", smoke: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true")

@@ -166,4 +166,6 @@ def main(out_path: str = "BENCH_planner.json", smoke: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     main(smoke="--smoke" in sys.argv[1:])

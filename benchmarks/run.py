@@ -51,16 +51,21 @@ SECTIONS = [
 ]
 
 
-def main() -> None:
+def main() -> int:
+    """Print every section; returns the number of sections that raised
+    (each prints a ``nan,ERROR`` row and the rest still run)."""
     print("name,us_per_call,derived")
+    failed = 0
     for title, fn in SECTIONS:
         print(f"# --- {title} ---")
         try:
             for name, us, derived in fn():
                 print(f"{name},{us:.3f},{derived}")
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest
+            failed += 1
             print(f"{title},nan,ERROR {type(e).__name__}: {e}")
+    return failed
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(1 if main() else 0)

@@ -284,6 +284,8 @@ def main(out_path: str = "BENCH_train.json", smoke: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true")

@@ -12,18 +12,19 @@ through, with two implementations:
   to the in-place masked-ufunc scans in ``sim.py``.  No dependencies
   beyond NumPy; the reference for the ≤1e-9 agreement tests.
 * :class:`JaxScanEngine` (``engine="jax"``) — the same Hillis-Steele
-  passes as ``jax.jit``-compiled kernels, ``jax.vmap``-batched over the
-  trailing batch axis.  jax is an *optional* dependency
-  (requirements-dev.txt): constructing the engine without it raises a
-  clear error, and everything else in the simulator keeps working.
-  Kernels run under a *scoped* ``jax.experimental.enable_x64`` context —
-  the compiled executor is held to ≤1e-9 agreement with the interpreter,
+  passes as ``jax.jit``-compiled kernels over the ``(k, columns)``
+  layout, on whatever device jax picks (the TPU when one is attached).
+  Kernels run under the *scoped* ``jax.enable_x64(True)`` context — the
+  compiled executor is held to ≤1e-9 agreement with the interpreter,
   which float32 cannot meet — without flipping the process-global x64
   flag (other jax users in the same process, e.g. the Layer-B models,
-  keep their own precision defaults).
+  keep their own precision defaults).  An instance counts its kernel
+  dispatches and records the devices its outputs lived on, so a caller
+  that passes its own instance can see what one replay sent to the
+  device.
 
-Engines are stateless beyond caches, so one instance serves every
-compiled program; executors resolve a per-call ``engine=`` argument
+Engines are stateless beyond caches and counters, so one instance serves
+every compiled program; executors resolve a per-call ``engine=`` argument
 through :func:`resolve_engine` (``None`` → numpy).  The combine masks
 arrive as the precomputed ``takes`` lists of
 :func:`~repro.core.exanet.sim.scan_take_masks` — shift offsets are
@@ -33,8 +34,11 @@ operands.
 
 from __future__ import annotations
 
+import collections
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.exanet.sim import (segmented_maxplus_scan,
@@ -56,86 +60,73 @@ class NumpyScanEngine:
         return segmented_running_max(v, takes)
 
 
-_jax = None
-_enable_x64 = None
-
-
-def _load_jax():
-    """Import jax lazily; raise a clear error when the optional
-    dependency is absent (mirrors the hypothesis pattern in tests)."""
-    global _jax, _enable_x64
-    if _jax is None:
-        try:
-            import jax
-            from jax.experimental import enable_x64
-        except ImportError as e:
-            raise RuntimeError(
-                "scan engine 'jax' requires the optional jax dependency "
-                "(pip install \"jax[cpu]\"; see requirements-dev.txt). "
-                "The default engine='numpy' needs nothing extra."
-            ) from e
-        _jax, _enable_x64 = jax, enable_x64
-    return _jax
-
-
 @functools.lru_cache(maxsize=None)
 def _maxplus_kernel(shifts: tuple):
-    """jit+vmap max-plus kernel for one static shift sequence.  One
-    Hillis-Steele pass composes ``(D1,T1) then (D2,T2)`` into
-    ``(D1+D2, max(T1+D2, T2))`` where the take mask allows; vmap runs
-    every batch column through the same (k,)-vector kernel."""
-    jax = _load_jax()
-    jnp = jax.numpy
+    """Jitted max-plus kernel over ``(k, columns)`` for one static shift
+    sequence.  One Hillis-Steele pass composes ``(D1,T1) then (D2,T2)``
+    into ``(D1+D2, max(T1+D2, T2))`` where the ``(k - s, 1)`` take mask
+    allows.  The kernel works on the 2-D layout directly: ``jax.vmap`` of
+    the per-column form over a single column is miscompiled by the CPU
+    backend of jax 0.9.0."""
 
-    def one(D, T, masks):
+    def kernel(D, T, masks):
         for s, m in zip(shifts, masks):
             T = T.at[s:].set(jnp.where(
                 m, jnp.maximum(T[:-s] + D[s:], T[s:]), T[s:]))
             D = D.at[s:].set(jnp.where(m, D[:-s] + D[s:], D[s:]))
         return D, T
 
-    return jax.jit(jax.vmap(one, in_axes=(1, 1, None), out_axes=1))
+    return jax.jit(kernel)
 
 
 @functools.lru_cache(maxsize=None)
 def _running_max_kernel(shifts: tuple):
-    jax = _load_jax()
-    jnp = jax.numpy
 
-    def one(v, masks):
+    def kernel(v, masks):
         for s, m in zip(shifts, masks):
             v = v.at[s:].set(jnp.where(m, jnp.maximum(v[:-s], v[s:]),
                                        v[s:]))
         return v
 
-    return jax.jit(jax.vmap(one, in_axes=(1, None), out_axes=1))
+    return jax.jit(kernel)
 
 
 class JaxScanEngine:
-    """``jax.jit`` + ``jax.vmap`` lane of the same scan kernels.
+    """``jax.jit`` lane of the same scan kernels.
 
     Jitted kernels are cached per shift sequence (the static part of a
-    stage's ``takes``); the 1-D mask operands are cached per ``takes``
-    list identity — the cache holds a reference to the list itself, so a
-    recycled ``id()`` can never alias a dead stage.  Inputs and outputs
-    are NumPy arrays: conversion happens at this boundary only, and the
-    surrounding gather/scatter bookkeeping stays NumPy either way.
+    stage's ``takes``); the ``(k - s, 1)`` mask operands are cached per
+    ``takes`` list identity — the cache holds a reference to the list
+    itself, so a recycled ``id()`` can never alias a dead stage.  Inputs
+    and outputs are NumPy arrays: conversion happens at this boundary
+    only, and the surrounding gather/scatter bookkeeping stays NumPy
+    either way.
+
+    ``dispatches`` counts kernel calls per ``(kernel, shifts, (k,
+    columns))`` — each key is one compiled program, so a cold process
+    pays one compile per key — and ``devices`` collects the devices the
+    kernels' outputs lived on.
     """
 
     name = "jax"
 
     def __init__(self):
-        _load_jax()
         self._takes_cache: dict = {}
+        self.dispatches: collections.Counter = collections.Counter()
+        self.devices: set = set()
 
     def _prep(self, takes):
         key = id(takes)
         ent = self._takes_cache.get(key)
         if ent is None or ent[0] is not takes:
             shifts = tuple(int(s) for s, _ in takes)
-            masks = tuple(np.ascontiguousarray(m[:, 0]) for _, m in takes)
+            masks = tuple(np.ascontiguousarray(m) for _, m in takes)
             ent = self._takes_cache[key] = (takes, shifts, masks)
         return ent[1], ent[2]
+
+    def _record(self, kernel: str, shifts: tuple, out):
+        self.dispatches[(kernel, shifts, out.shape)] += 1
+        self.devices.update(out.devices())
 
     def maxplus_scan(self, D, T, takes):
         shifts, masks = self._prep(takes)
@@ -148,8 +139,9 @@ class JaxScanEngine:
         # scoped x64: the ≤1e-9 contract needs float64, but the flag must
         # not leak to other jax users in the process (the x64 state keys
         # the jit cache, so scoping is sound)
-        with _enable_x64():
+        with jax.enable_x64(True):
             Dj, Tj = _maxplus_kernel(shifts)(D, T, masks)
+            self._record("maxplus", shifts, Tj)
             return (np.asarray(Dj).reshape(shape),
                     np.asarray(Tj).reshape(shape))
 
@@ -158,8 +150,9 @@ class JaxScanEngine:
         shape = v.shape
         if v.ndim != 2:
             v = np.ascontiguousarray(v).reshape(shape[0], -1)
-        with _enable_x64():
+        with jax.enable_x64(True):
             out = _running_max_kernel(shifts)(v, masks)
+            self._record("running_max", shifts, out)
             return np.asarray(out).reshape(shape)
 
 
@@ -167,32 +160,16 @@ class JaxScanEngine:
 #: shares it, and ``resolve_engine(None)`` is an attribute read)
 NUMPY = NumpyScanEngine()
 
-_engines: dict = {"numpy": NUMPY}
-
-
-def available_engines() -> list[str]:
-    """Engine names usable in this environment (``jax`` only when the
-    optional dependency imports)."""
-    names = ["numpy"]
-    try:
-        _load_jax()
-    except RuntimeError:
-        pass
-    else:
-        names.append("jax")
-    return names
+_engines: dict = {"numpy": NUMPY, "jax": JaxScanEngine()}
 
 
 def get_scan_engine(name: str = "numpy"):
-    """The shared engine instance for ``name``.  Raises ``ValueError``
-    for unknown names and ``RuntimeError`` when ``"jax"`` is requested
-    without jax installed."""
+    """The shared engine instance for ``name``; ``ValueError`` for
+    unknown names."""
     eng = _engines.get(name)
     if eng is None:
-        if name != "jax":
-            raise ValueError(f"unknown scan engine {name!r}; "
-                             f"options: ['jax', 'numpy']")
-        eng = _engines["jax"] = JaxScanEngine()
+        raise ValueError(f"unknown scan engine {name!r}; "
+                         f"options: {sorted(_engines)}")
     return eng
 
 

@@ -5,6 +5,8 @@ and reports per-request latency in *engine steps* (submit -> done) — the
 same quantity the simulated lane (``repro.serve.sim`` +
 ``benchmarks/serve_sweep.py``) reports in simulated microseconds, so the
 real engine and the simulator publish comparable distributions.
+``main`` returns the summary it prints, so a caller (``chip_smoke.py``)
+can check it.
 """
 
 from __future__ import annotations
@@ -21,7 +23,10 @@ from repro.models import build_model
 from repro.serve.engine import ServeEngine
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Serve synthetic requests; returns ``{"requests", "done", "tokens",
+    "steps", "seconds", "vocab_size", "outputs"}`` with ``outputs`` the
+    generated token list per request id."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="exanest-lm-100m",
                     choices=ALL_ARCHS + EXTRA_ARCHS)
@@ -54,6 +59,9 @@ def main(argv=None):
     toks = sum(len(eng.result(r) or []) for r in rids)
     print(f"served {done}/{args.requests} requests, {toks} tokens in "
           f"{steps} engine steps, {dt:.2f}s ({toks/max(dt,1e-9):.1f} tok/s)")
+    summary = {"requests": args.requests, "done": done, "tokens": toks,
+               "steps": steps, "seconds": dt, "vocab_size": cfg.vocab_size,
+               "outputs": {r: eng.result(r) for r in rids}}
     stats = eng.request_steps()
     if stats:
         lat = np.sort(np.array([d - s for s, d in stats.values()],
@@ -66,7 +74,10 @@ def main(argv=None):
             s, d = stats[rid]
             print(f"  request {rid}: submit@{s} done@{d} "
                   f"({d - s} steps, {len(eng.result(rid) or [])} tokens)")
+    return summary
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

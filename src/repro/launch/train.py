@@ -2,7 +2,8 @@
 
 On real TPU pods this process runs per-host under the standard JAX
 distributed bootstrap; on CPU it drives the reduced config end-to-end (the
-same step function the dry-run lowers at full scale).
+same step function the dry-run lowers at full scale).  ``main`` returns
+the loss of every step.
 """
 
 from __future__ import annotations
@@ -21,7 +22,9 @@ from repro.train.loop import Trainer
 from repro.train.optimizer import AdamWConfig
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Train on synthetic tokens; returns ``{"losses", "straggles"}``
+    with one float loss per step run."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="exanest-lm-100m",
                     choices=ALL_ARCHS + EXTRA_ARCHS)
@@ -48,11 +51,13 @@ def main(argv=None):
     data = SyntheticTokens(cfg, batch=args.batch, seq=args.seq)
     step_fn = trainer.make_step()
     mon = StragglerMonitor()
+    losses = []
 
     def one_step(st, i):
         st, metrics = step_fn(st, data.batch_at(i))
+        losses.append(float(metrics["loss"]))
         if i % 10 == 0:
-            print(f"step {i} loss {float(metrics['loss']):.4f}")
+            print(f"step {i} loss {losses[-1]:.4f}")
         return st
 
     os.makedirs(args.ckpt_dir, exist_ok=True)
@@ -60,7 +65,10 @@ def main(argv=None):
                                    ckpt_dir=args.ckpt_dir,
                                    ckpt_every=args.ckpt_every, straggler=mon)
     print(f"done: {args.steps} steps, straggles={log['straggles']}")
+    return {"losses": losses, "straggles": log["straggles"]}
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -1,7 +1,8 @@
 """Target hardware constants (TPU v5e) for roofline terms and CommPolicy.
 
-The container is CPU-only; these constants describe the TARGET chip per the
-assignment: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Published peaks of one TPU v5e chip (Google Cloud documentation, "TPU
+v5e"): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.  The roofline
+terms use them as the target chip; they are not measurements.
 """
 
 import dataclasses

@@ -3,10 +3,9 @@
 Three contracts:
 
 * **Engines** — ``engine="numpy"`` and ``engine="jax"`` are
-  interchangeable scan backends; resolution errors are clear, and a
-  missing jax degrades gracefully (the numpy default keeps working, the
-  jax request names requirements-dev.txt).  jax-lane tests
-  ``importorskip`` the dependency, mirroring the hypothesis pattern.
+  interchangeable scan backends; resolution errors are clear, and the
+  jax lane scopes float64 to its own kernels (the process-global x64
+  flag never flips).
 * **Batched == per-binding** to ≤1e-9 for both engines: message-size
   grids and arrival-offset ``t0`` columns through
   ``run_schedule_many``, fuzzed Program batches (mixed structures,
@@ -20,7 +19,6 @@ Three contracts:
 """
 
 import random
-import sys
 
 import numpy as np
 import pytest
@@ -38,8 +36,6 @@ MPI = ExanetMPI()
 
 @pytest.fixture(params=["numpy", "jax"])
 def engine(request):
-    if request.param == "jax":
-        pytest.importorskip("jax")
     return request.param
 
 
@@ -59,20 +55,22 @@ def test_resolve_engine_normalization():
         se.resolve_engine(3)
 
 
-def test_missing_jax_degrades_gracefully(monkeypatch):
-    """Without the optional dependency the numpy default still works and
-    the jax request raises a clear install hint (satellite: graceful
-    degradation; simulated by blocking the import)."""
-    monkeypatch.setattr(se, "_jax", None)
-    monkeypatch.delitem(se._engines, "jax", raising=False)
-    monkeypatch.setitem(sys.modules, "jax", None)  # import jax -> ImportError
-    assert se.available_engines() == ["numpy"]
-    with pytest.raises(RuntimeError, match="requirements-dev.txt"):
-        se.get_scan_engine("jax")
-    # the default engine never touches jax
-    r = MPI.run_schedule_many(RecursiveDoublingAllreduce(), (4096,), 8,
-                              engine="numpy")
-    assert r.latency_us.shape == (1,)
+def test_jax_engine_scopes_x64_to_its_kernels():
+    """A jax-lane replay computes in float64 on the device yet leaves
+    the process-global x64 flag off, so co-resident jax code (the LM
+    stack) keeps float32 defaults."""
+    import jax
+    import jax.numpy as jnp
+    eng = se.JaxScanEngine()
+    r = MPI.run_schedule_many(RecursiveDoublingAllreduce(), (4096, 65536),
+                              16, engine=eng)
+    assert r.latency_us.dtype == np.float64
+    assert sum(eng.dispatches.values()) > 0
+    assert eng.devices and all(d.platform == jax.default_backend()
+                               for d in eng.devices)
+    assert jax.config.jax_enable_x64 is False
+    assert jnp.zeros(3).dtype == jnp.float32
+    assert jnp.asarray(1.5).dtype == jnp.float32
 
 
 # ------------------------------------------------- batched schedule runs
