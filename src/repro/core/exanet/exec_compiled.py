@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.core.exanet.scan_engine import NUMPY, resolve_engine
 from repro.core.exanet.sim import ResourceState, scan_take_masks
+from repro.runtime import spans
 
 NEG_INF = float("-inf")
 
@@ -218,15 +219,18 @@ class LinkDegrade:
         """Recomputed per-column constants of one level (cached per level
         object: levels are compile-time artifacts that outlive runs)."""
         out = self._cache.get(id(lv))
-        if out is not None:
-            return out
+        if out is None:
+            with spans.span("transport.link_consts"):
+                out = self._cache[id(lv)] = self._consts(lv)
+        return out
+
+    def _consts(self, lv) -> dict:
         ids = lv.link_ids
         if ids is None or ids.size == 0:
             out = {"e_const": lv.e_const, "eager_pb": lv.eager_pb}
             if hasattr(lv, "handshake"):
                 out.update(handshake=lv.handshake, stream_pb=lv.stream_pb,
                            hop=lv.hop)
-            self._cache[id(lv)] = out
             return out
         mask = ids >= 0                                    # (k, L)
         idx = np.where(mask, ids, 0)
@@ -248,7 +252,6 @@ class LinkDegrade:
             out["stream_pb"] = np.where(has, 8.0 / (bw * 1000.0),
                                         lv.stream_pb)
             out["hop"] = lv.hop + exsum
-        self._cache[id(lv)] = out
         return out
 
 
@@ -763,6 +766,7 @@ class RoundProgram(VecTransport):
         return bound
 
     # ------------------------------------------------------------ execution
+    @spans.traced("transport.level")
     def _exec_exchange_round(self, state, r, rb, t_issue, B):
         """All sends of an exchange round: the eager branch runs once
         round-wide (packetizer sharing is always same-stage), the
@@ -818,6 +822,7 @@ class RoundProgram(VecTransport):
             complete, sender_free = comp_e, sfree_e
         return complete, sender_free
 
+    @spans.traced("transport.level")
     def _exec_level(self, state, lv, t_issue, rb):
         """Run one level's sends through both transports; returns
         (complete, sender_free) in level order.  Mixed column-uniform

@@ -37,6 +37,7 @@ from repro.core.exanet.schedules import (ALLREDUCE_SCHEDULES,
                                          RecursiveDoublingAllreduce,
                                          ScatterBinomial)
 from repro.core.exanet.topology import Path, Topology
+from repro.runtime import spans
 
 
 @dataclasses.dataclass
@@ -721,6 +722,7 @@ class ExanetMPI:
                              f"got shape {arr.shape}")
         return {k: arr for k in _faults.all_link_keys(self.topo)}
 
+    @spans.traced("replay.degrade")
     def _link_degrade(self, slow_map, extra_map, N):
         """Build the :class:`LinkDegrade` run-time axis: (n_resource_rows,
         N) slowdown/extra-latency arrays indexed by the engine's directed
@@ -806,104 +808,106 @@ class ExanetMPI:
         """
         from repro.core.exanet.program_compiled import (extract_data,
                                                         rebind_program)
-        base = extract_data(prog)
-        slow_map = self._norm_link_axis(link_scale, "link_scale")
-        extra_map = self._norm_link_axis(link_latency_us, "link_latency_us")
-        if slow_map:
-            for k, v in slow_map.items():
-                if (v < 1.0).any():
-                    raise ValueError(
-                        f"link_scale[{k}] has factors < 1 (a speedup); "
-                        "degradation factors must be >= 1")
-        N = None
-        for nm, a in (("compute_scale", compute_scale),
-                      ("byte_scale", byte_scale),
-                      ("site_scale", site_scale), ("t0", t0),
-                      ("link_scale", slow_map),
-                      ("link_latency_us", extra_map)):
-            if a is not None:
-                if isinstance(a, dict):
-                    n = len(next(iter(a.values())))
-                    bad = {k: len(v) for k, v in a.items() if len(v) != n}
-                    if bad:
-                        raise ValueError(f"{nm} values disagree on N: "
-                                         f"{bad} vs {n}")
+        with spans.span("replay.prepare"):
+            base = extract_data(prog)
+            slow_map = self._norm_link_axis(link_scale, "link_scale")
+            extra_map = self._norm_link_axis(link_latency_us,
+                                             "link_latency_us")
+            if slow_map:
+                for k, v in slow_map.items():
+                    if (v < 1.0).any():
+                        raise ValueError(
+                            f"link_scale[{k}] has factors < 1 (a speedup); "
+                            "degradation factors must be >= 1")
+            N = None
+            for nm, a in (("compute_scale", compute_scale),
+                          ("byte_scale", byte_scale),
+                          ("site_scale", site_scale), ("t0", t0),
+                          ("link_scale", slow_map),
+                          ("link_latency_us", extra_map)):
+                if a is not None:
+                    if isinstance(a, dict):
+                        n = len(next(iter(a.values())))
+                        bad = {k: len(v) for k, v in a.items() if len(v) != n}
+                        if bad:
+                            raise ValueError(f"{nm} values disagree on N: "
+                                             f"{bad} vs {n}")
+                    else:
+                        n = np.asarray(a).shape[-1]
+                    if N is None:
+                        N = n
+                    elif n != N:
+                        raise ValueError(f"{nm} disagrees on N ({n} vs {N})")
+            if N is None:
+                raise ValueError(
+                    "give at least one of compute_scale / byte_scale / "
+                    "site_scale / link_scale / link_latency_us / t0")
+            comp_cols = post_cols = site_cols = t0_cols = None
+            base_comp = np.array(base[0], dtype=np.float64)
+            base_post = np.array(base[1], dtype=np.float64)
+            base_site = np.array(base[2], dtype=np.float64)
+            if compute_scale is not None:
+                cs = np.asarray(compute_scale, dtype=np.float64)
+                if cs.ndim == 1:
+                    comp_cols = base_comp[:, None] * cs[None, :]
+                elif cs.shape[0] == prog.nranks:
+                    art0 = self.program_artifact(prog)
+                    comp_cols = base_comp[:, None] * \
+                        cs[art0._static.compute_rank]
+                elif cs.shape[0] == len(base_comp):
+                    # per-compute-slot skew: the train co-sim's bucket-layout
+                    # axis (candidates move backward compute between buckets,
+                    # not between ranks)
+                    comp_cols = base_comp[:, None] * cs
                 else:
-                    n = np.asarray(a).shape[-1]
-                if N is None:
-                    N = n
-                elif n != N:
-                    raise ValueError(f"{nm} disagrees on N ({n} vs {N})")
-        if N is None:
-            raise ValueError(
-                "give at least one of compute_scale / byte_scale / "
-                "site_scale / link_scale / link_latency_us / t0")
-        comp_cols = post_cols = site_cols = t0_cols = None
-        base_comp = np.array(base[0], dtype=np.float64)
-        base_post = np.array(base[1], dtype=np.float64)
-        base_site = np.array(base[2], dtype=np.float64)
-        if compute_scale is not None:
-            cs = np.asarray(compute_scale, dtype=np.float64)
-            if cs.ndim == 1:
-                comp_cols = base_comp[:, None] * cs[None, :]
-            elif cs.shape[0] == prog.nranks:
-                art0 = self.program_artifact(prog)
-                comp_cols = base_comp[:, None] * \
-                    cs[art0._static.compute_rank]
-            elif cs.shape[0] == len(base_comp):
-                # per-compute-slot skew: the train co-sim's bucket-layout
-                # axis (candidates move backward compute between buckets,
-                # not between ranks)
-                comp_cols = base_comp[:, None] * cs
-            else:
-                raise ValueError(
-                    f"compute_scale must be (N,), (nranks, N) or "
-                    f"(n_computes, N); got {cs.shape} for "
-                    f"nranks={prog.nranks}, n_computes={len(base_comp)}")
-        if byte_scale is not None:
-            bs = np.asarray(byte_scale, dtype=np.float64)
-            if bs.ndim == 1:
-                post_cols = np.rint(base_post[:, None] * bs[None, :])
-            else:
-                if bs.shape[0] != len(base_post):
                     raise ValueError(
-                        f"byte_scale must be (N,) or (n_posts, N); got "
-                        f"{bs.shape} for n_posts={len(base_post)}")
-                post_cols = np.rint(base_post[:, None] * bs)
-        if site_scale is not None:
-            ss = np.asarray(site_scale, dtype=np.float64)
-            if ss.ndim == 1:
-                site_cols = np.rint(base_site[:, None] * ss[None, :]
-                                    ).astype(np.int64)
-            else:
-                if ss.shape[0] != len(base_site):
+                        f"compute_scale must be (N,), (nranks, N) or "
+                        f"(n_computes, N); got {cs.shape} for "
+                        f"nranks={prog.nranks}, n_computes={len(base_comp)}")
+            if byte_scale is not None:
+                bs = np.asarray(byte_scale, dtype=np.float64)
+                if bs.ndim == 1:
+                    post_cols = np.rint(base_post[:, None] * bs[None, :])
+                else:
+                    if bs.shape[0] != len(base_post):
+                        raise ValueError(
+                            f"byte_scale must be (N,) or (n_posts, N); got "
+                            f"{bs.shape} for n_posts={len(base_post)}")
+                    post_cols = np.rint(base_post[:, None] * bs)
+            if site_scale is not None:
+                ss = np.asarray(site_scale, dtype=np.float64)
+                if ss.ndim == 1:
+                    site_cols = np.rint(base_site[:, None] * ss[None, :]
+                                        ).astype(np.int64)
+                else:
+                    if ss.shape[0] != len(base_site):
+                        raise ValueError(
+                            f"site_scale must be (N,) or (n_sites, N); got "
+                            f"{ss.shape} for n_sites={len(base_site)}")
+                    site_cols = np.rint(base_site[:, None] * ss
+                                        ).astype(np.int64)
+            if t0 is not None:
+                t0_cols = np.asarray(t0, dtype=np.float64)
+                if t0_cols.shape != (prog.nranks, N):
                     raise ValueError(
-                        f"site_scale must be (N,) or (n_sites, N); got "
-                        f"{ss.shape} for n_sites={len(base_site)}")
-                site_cols = np.rint(base_site[:, None] * ss
-                                    ).astype(np.int64)
-        if t0 is not None:
-            t0_cols = np.asarray(t0, dtype=np.float64)
-            if t0_cols.shape != (prog.nranks, N):
-                raise ValueError(
-                    f"t0 must be (nranks, N); got {t0_cols.shape} for "
-                    f"nranks={prog.nranks}, N={N}")
-        if (comp_cols is None and post_cols is None and site_cols is None
-                and (t0_cols is not None or slow_map or extra_map)):
-            # t0-/link-only sweep: bind_arrays infers N from payload
-            # arrays, so hold one of them constant across the N columns
-            if len(base_comp):
-                comp_cols = np.broadcast_to(
-                    base_comp[:, None], (len(base_comp), N))
-            elif len(base_post):
-                post_cols = np.broadcast_to(
-                    base_post[:, None], (len(base_post), N))
-            else:
-                site_cols = np.broadcast_to(
-                    np.array(base[2], dtype=np.int64)[:, None],
-                    (len(base_site), N))
-        plans = self._plan_program_sites(prog, plans)
-        art = self.program_artifact(prog)
+                        f"t0 must be (nranks, N); got {t0_cols.shape} for "
+                        f"nranks={prog.nranks}, N={N}")
+            if (comp_cols is None and post_cols is None and site_cols is None
+                    and (t0_cols is not None or slow_map or extra_map)):
+                # t0-/link-only sweep: bind_arrays infers N from payload
+                # arrays, so hold one of them constant across the N columns
+                if len(base_comp):
+                    comp_cols = np.broadcast_to(
+                        base_comp[:, None], (len(base_comp), N))
+                elif len(base_post):
+                    post_cols = np.broadcast_to(
+                        base_post[:, None], (len(base_post), N))
+                else:
+                    site_cols = np.broadcast_to(
+                        np.array(base[2], dtype=np.int64)[:, None],
+                        (len(base_site), N))
+            plans = self._plan_program_sites(prog, plans)
+            art = self.program_artifact(prog)
         bound = art.bind_arrays(prog, compute_us=comp_cols,
                                 post_nbytes=post_cols,
                                 site_nbytes=site_cols, plans=plans)

@@ -72,6 +72,7 @@ from repro.core.exanet.sim import ResourceState
 from repro.core.program import (Collective, Compute, Irecv, Isend, Program,
                                 ProgramError, ProgramExecutor, ProgramResult,
                                 Wait)
+from repro.runtime import spans
 
 
 # ---------------------------------------------------------------------------
@@ -774,6 +775,7 @@ class CompiledProgram(VecTransport):
             out.append((np.array(cols, dtype=np.int64), bound))
         return out
 
+    @spans.traced("replay.bind")
     def bind_arrays(self, prog: Program, *, compute_us=None,
                     post_nbytes=None, site_nbytes=None,
                     plans=None) -> _BoundIR:
@@ -938,46 +940,50 @@ class CompiledProgram(VecTransport):
         (:class:`~repro.core.exanet.exec_compiled.LinkDegrade`): every
         p2p level and spliced collective recomputes its link-derived
         constants per column (DESIGN.md §2.10)."""
-        self._eng = resolve_engine(engine)
-        self._deg = deg
-        st = self._static
-        B = bound.B
-        if deg is not None and deg.ncols not in (1, B):
-            raise ValueError(f"deg has {deg.ncols} columns, batch has {B}")
-        lowered = bound.lowered
-        state = ResourceState(lowered.n_rows, B)
-        C = np.zeros((st.n_segs, B))
-        if t0 is not None:
-            t0 = np.asarray(t0, dtype=np.float64)
-            if t0.ndim == 1:
-                t0 = t0[:, None]
-            if t0.shape != (self.nranks, 1) and t0.shape != (self.nranks, B):
-                raise ValueError(
-                    f"t0 must have shape ({self.nranks},) or "
-                    f"({self.nranks}, {B}), got {t0.shape}")
-            C[st.first_gid_arr] = t0
-        # virtual rows past the p2p events hold the per-(site, rank) exit
-        # clocks of nonblocking collectives, consumed by waits like any
-        # send-side completion
-        n_rows = len(st.events) + st.n_async * self.nranks
-        send_done = np.empty((n_rows, B))
-        recv_done = np.empty((n_rows, B))
-        for plan, bl in zip(lowered.levels, bound.levels):
-            if plan.p2p is not None:
-                self._exec_p2p_level(state, plan.p2p, bl, C, bound,
-                                     send_done, recv_done)
-            if plan.waits is not None:
-                self._exec_waits(plan.waits, C, bound, send_done, recv_done)
-            if plan.coll is not None:
-                self._exec_coll(state, plan.coll, C, bound, send_done)
-        final = C[st.last_gid_arr] + bound.seg_total[st.last_gid_arr]
-        latency = final.max(axis=0) if self.nranks else np.zeros(B)
-        return [ProgramResult(
-            float(latency[b]),
-            tuple(float(x) for x in final[:, b]),
-            tuple(float(x) for x in bound.rank_compute[:, b]),
-            len(st.events), len(st.sites)) for b in range(B)]
+        with spans.span("replay.run"):
+            self._eng = resolve_engine(engine)
+            self._deg = deg
+            st = self._static
+            B = bound.B
+            if deg is not None and deg.ncols not in (1, B):
+                raise ValueError(f"deg has {deg.ncols} columns, batch has {B}")
+            lowered = bound.lowered
+            state = ResourceState(lowered.n_rows, B)
+            C = np.zeros((st.n_segs, B))
+            if t0 is not None:
+                t0 = np.asarray(t0, dtype=np.float64)
+                if t0.ndim == 1:
+                    t0 = t0[:, None]
+                if t0.shape not in ((self.nranks, 1), (self.nranks, B)):
+                    raise ValueError(
+                        f"t0 must have shape ({self.nranks},) or "
+                        f"({self.nranks}, {B}), got {t0.shape}")
+                C[st.first_gid_arr] = t0
+            # virtual rows past the p2p events hold the per-(site, rank) exit
+            # clocks of nonblocking collectives, consumed by waits like any
+            # send-side completion
+            n_rows = len(st.events) + st.n_async * self.nranks
+            send_done = np.empty((n_rows, B))
+            recv_done = np.empty((n_rows, B))
+            for plan, bl in zip(lowered.levels, bound.levels):
+                if plan.p2p is not None:
+                    self._exec_p2p_level(state, plan.p2p, bl, C, bound,
+                                         send_done, recv_done)
+                if plan.waits is not None:
+                    self._exec_waits(plan.waits, C, bound, send_done,
+                                     recv_done)
+                if plan.coll is not None:
+                    self._exec_coll(state, plan.coll, C, bound, send_done)
+            final = C[st.last_gid_arr] + bound.seg_total[st.last_gid_arr]
+            latency = final.max(axis=0) if self.nranks else np.zeros(B)
+        with spans.span("replay.results"):
+            return [ProgramResult(
+                float(latency[b]),
+                tuple(float(x) for x in final[:, b]),
+                tuple(float(x) for x in bound.rank_compute[:, b]),
+                len(st.events), len(st.sites)) for b in range(B)]
 
+    @spans.traced("transport.level")
     def _exec_p2p_level(self, state, pl: _PLevel, bl: _BoundLevel, C,
                         bound, send_done, recv_done) -> None:
         t_send = C[pl.send_seg] + bound.post_off[pl.send_post]
@@ -1003,6 +1009,7 @@ class CompiledProgram(VecTransport):
         recv_done[pl.ev] = np.where(bl.is_rdv, comp_r,
                                     np.maximum(comp_e, t_recv))
 
+    @spans.traced("transport.waits")
     def _exec_waits(self, wp: _WaitPlan, C, bound, send_done,
                     recv_done) -> None:
         exit_ = C[wp.prev] + bound.seg_total[wp.prev]
@@ -1013,6 +1020,7 @@ class CompiledProgram(VecTransport):
             exit_[wp.with_req] = np.maximum(exit_[wp.with_req], gm)
         C[wp.target] = exit_
 
+    @spans.traced("transport.collective")
     def _exec_coll(self, state, slot: _CollSlot, C, bound,
                    send_done=None) -> None:
         if slot.entry_item is None:         # blocking: entry ends a segment

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.exanet.sim import (segmented_maxplus_scan,
                                    segmented_running_max)
+from repro.runtime.spans import span
 
 
 class NumpyScanEngine:
@@ -67,28 +68,30 @@ def _maxplus_kernel(shifts: tuple):
     into ``(D1+D2, max(T1+D2, T2))`` where the ``(k - s, 1)`` take mask
     allows.  The kernel works on the 2-D layout directly: ``jax.vmap`` of
     the per-column form over a single column is miscompiled by the CPU
-    backend of jax 0.9.0."""
+    backend of jax 0.9.0.  The function's name is the compiled program's
+    (``jit_maxplus_scan``), which a profiler trace shows per launch."""
 
-    def kernel(D, T, masks):
+    def maxplus_scan(D, T, masks):
         for s, m in zip(shifts, masks):
             T = T.at[s:].set(jnp.where(
                 m, jnp.maximum(T[:-s] + D[s:], T[s:]), T[s:]))
             D = D.at[s:].set(jnp.where(m, D[:-s] + D[s:], D[s:]))
         return D, T
 
-    return jax.jit(kernel)
+    return jax.jit(maxplus_scan)
 
 
 @functools.lru_cache(maxsize=None)
 def _running_max_kernel(shifts: tuple):
+    """Jitted segmented running maximum (``jit_running_max``)."""
 
-    def kernel(v, masks):
+    def running_max(v, masks):
         for s, m in zip(shifts, masks):
             v = v.at[s:].set(jnp.where(m, jnp.maximum(v[:-s], v[s:]),
                                        v[s:]))
         return v
 
-    return jax.jit(kernel)
+    return jax.jit(running_max)
 
 
 class JaxScanEngine:
@@ -105,7 +108,16 @@ class JaxScanEngine:
     ``dispatches`` counts kernel calls per ``(kernel, shifts, (k,
     columns))`` — each key is one compiled program, so a cold process
     pays one compile per key — and ``devices`` collects the devices the
-    kernels' outputs lived on.
+    kernels' outputs lived on (read once per key: one compiled program
+    always runs where it first ran).  ``bytes_in`` sums the ``nbytes`` of
+    every operand a kernel call receives, masks included, and
+    ``bytes_out`` those of every output fetched back: what crosses the
+    host-device link.
+
+    Each call is a span ``scan.maxplus`` or ``scan.running_max``
+    (:mod:`repro.runtime.spans`) holding ``scan.call`` (the operands'
+    transfer and the launch) and ``scan.fetch`` (waiting for the device
+    and copying the outputs back).
     """
 
     name = "jax"
@@ -114,6 +126,8 @@ class JaxScanEngine:
         self._takes_cache: dict = {}
         self.dispatches: collections.Counter = collections.Counter()
         self.devices: set = set()
+        self.bytes_in = 0
+        self.bytes_out = 0
 
     def _prep(self, takes):
         key = id(takes)
@@ -125,35 +139,54 @@ class JaxScanEngine:
         return ent[1], ent[2]
 
     def _record(self, kernel: str, shifts: tuple, out):
-        self.dispatches[(kernel, shifts, out.shape)] += 1
-        self.devices.update(out.devices())
+        key = (kernel, shifts, out.shape)
+        if key not in self.dispatches:
+            self.devices.update(out.devices())
+        self.dispatches[key] += 1
 
     def maxplus_scan(self, D, T, takes):
-        shifts, masks = self._prep(takes)
-        shape = T.shape
-        if D.shape != shape:
-            D = np.broadcast_to(D, shape)
-        if T.ndim != 2:
-            D = np.ascontiguousarray(D).reshape(shape[0], -1)
-            T = np.ascontiguousarray(T).reshape(shape[0], -1)
-        # scoped x64: the ≤1e-9 contract needs float64, but the flag must
-        # not leak to other jax users in the process (the x64 state keys
-        # the jit cache, so scoping is sound)
-        with jax.enable_x64(True):
-            Dj, Tj = _maxplus_kernel(shifts)(D, T, masks)
-            self._record("maxplus", shifts, Tj)
-            return (np.asarray(Dj).reshape(shape),
-                    np.asarray(Tj).reshape(shape))
+        with span("scan.maxplus"):
+            shifts, masks = self._prep(takes)
+            shape = T.shape
+            if D.shape != shape:
+                D = np.broadcast_to(D, shape)
+            if T.ndim != 2:
+                D = np.ascontiguousarray(D).reshape(shape[0], -1)
+                T = np.ascontiguousarray(T).reshape(shape[0], -1)
+            self.bytes_in += D.nbytes + T.nbytes + _nbytes(masks)
+            # scoped x64: the ≤1e-9 contract needs float64, but the flag
+            # must not leak to other jax users in the process (the x64
+            # state keys the jit cache, so scoping is sound)
+            with jax.enable_x64(True):
+                kernel = _maxplus_kernel(shifts)
+                with span("scan.call"):
+                    Dj, Tj = kernel(D, T, masks)
+                self._record("maxplus", shifts, Tj)
+                with span("scan.fetch"):
+                    D, T = np.asarray(Dj), np.asarray(Tj)
+            self.bytes_out += D.nbytes + T.nbytes
+            return D.reshape(shape), T.reshape(shape)
 
     def running_max(self, v, takes):
-        shifts, masks = self._prep(takes)
-        shape = v.shape
-        if v.ndim != 2:
-            v = np.ascontiguousarray(v).reshape(shape[0], -1)
-        with jax.enable_x64(True):
-            out = _running_max_kernel(shifts)(v, masks)
-            self._record("running_max", shifts, out)
-            return np.asarray(out).reshape(shape)
+        with span("scan.running_max"):
+            shifts, masks = self._prep(takes)
+            shape = v.shape
+            if v.ndim != 2:
+                v = np.ascontiguousarray(v).reshape(shape[0], -1)
+            self.bytes_in += v.nbytes + _nbytes(masks)
+            with jax.enable_x64(True):
+                kernel = _running_max_kernel(shifts)
+                with span("scan.call"):
+                    out = kernel(v, masks)
+                self._record("running_max", shifts, out)
+                with span("scan.fetch"):
+                    v = np.asarray(out)
+            self.bytes_out += v.nbytes
+            return v.reshape(shape)
+
+
+def _nbytes(arrays) -> int:
+    return sum(a.nbytes for a in arrays)
 
 
 #: the default engine instance (module-level: every compiled program
