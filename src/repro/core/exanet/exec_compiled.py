@@ -139,7 +139,9 @@ def _dst_grouping(dst, positions=None):
     return perm, np.flatnonzero(first), sd[first]
 
 
-@dataclasses.dataclass
+# compared and hashed by identity: the jax engine keys a level's device
+# constants on the level itself, weakly (scan_engine.JaxScanEngine)
+@dataclasses.dataclass(eq=False)
 class _Level:
     sel: np.ndarray                  # (k,) round-send indices, ascending
     # per-send constants, shaped (k, 1) for broadcasting over the batch
@@ -506,6 +508,16 @@ class VecTransport:
             hop = _deg_col(c["hop"], cols)
             uni = False
         stream = nbl * stream_pb
+        eng = self._eng
+        if hasattr(eng, "rdv_level"):
+            # an unmasked level over every column runs as one dispatch;
+            # masked and column-split levels keep the staged chain
+            if act is None and cols is None and eng.fuses_levels:
+                comp = eng.rdv_level(state, lv, t_issue + handshake, stream,
+                                     uni, self._r5_occ,
+                                     self._rdma_startup) + hop
+                return comp, comp
+            eng.levels_staged += 1
         st = lv.r5
         r = self._stage_acquire(state, st, t_issue + handshake,
                                 self._r5_occ, act, True, cols)

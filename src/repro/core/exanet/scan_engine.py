@@ -29,13 +29,23 @@ through :func:`resolve_engine` (``None`` → numpy).  The combine masks
 arrive as the precomputed ``takes`` lists of
 :func:`~repro.core.exanet.sim.scan_take_masks` — shift offsets are
 static per stage (they key the jitted kernel cache), masks are traced
-operands.
+operands.  The per-stage kernels keep their masks on the host and send
+them with every call.
+
+The jax engine also runs a whole rendez-vous level's acquire chain
+(R5 → DMA source → link hops → DMA destination) as one dispatch,
+:meth:`JaxScanEngine.rdv_level`: the level's index arrays and masks are
+put on the device once per level object and stay there, and each call
+sends only the chain's issue times, stream durations and the free times
+of the rows the level touches.  The transport hands it a level whenever
+the level runs unmasked over every column (DESIGN.md §2.5).
 """
 
 from __future__ import annotations
 
 import collections
 import functools
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -94,40 +104,193 @@ def _running_max_kernel(shifts: tuple):
     return jax.jit(running_max)
 
 
+#: per-stage forms of a level program: no row repeats, group-constant
+#: durations (one running maximum), or the general max-plus scan
+_UNIQUE, _RUNNING_MAX, _MAXPLUS = 0, 1, 2
+
+
+@functools.lru_cache(maxsize=None)
+def _rdv_level_kernel(r5_occ: float, rdma_startup: float):
+    """Jitted acquire chain of one rendez-vous level (``jit_rdv_level``):
+    the stages of ``VecTransport._run_rdv`` in order, with the same
+    arithmetic, as one ``lax.scan`` over stages so the program holds one
+    stage body whatever the level's depth.
+
+    The operand ``x`` stacks the issue times ``t`` (k rows), the stream
+    durations (k rows) and the free times of the level's u rows; the
+    output stacks the times each send's stream ends (k rows) and the
+    rows' new free times.  ``consts`` holds per stage (leading axis) the
+    level positions it gathers (padded to k, extra acquires inert: they
+    read a spare row, their masks are off and nothing reads them back),
+    each acquire's row among the u, combine masks, within-group ordinals,
+    which rows it writes back from which acquire, and where its starts
+    land in level order; ``forms`` holds each stage's form.  A row may be
+    acquired by several stages (a program level lets a later send take a
+    row at a later stage), so each stage reads the free times the stages
+    before it wrote."""
+
+    def stage(stream, carry, c):
+        cur, occupied, free = carry
+        sperm = c["sperm"]
+        ts = cur[sperm]
+        ds = jnp.where(c["r5"], r5_occ, stream[sperm])
+        F0 = free[c["ridx"]]
+        v = ts - c["kpos"] * ds
+        D, T = ds, ts + ds
+        for p, m in enumerate(c["masks"]):
+            s = 1 << p
+            v = v.at[s:].set(jnp.where(m, jnp.maximum(v[:-s], v[s:]),
+                                       v[s:]))
+            T = T.at[s:].set(jnp.where(
+                m, jnp.maximum(T[:-s] + D[s:], T[s:]), T[s:]))
+            D = D.at[s:].set(jnp.where(m, D[:-s] + D[s:], D[s:]))
+        unique = c["form"] == _UNIQUE
+        first = jnp.maximum(ts, F0)
+        f_after = jnp.where(
+            unique, first + ds,
+            jnp.where(c["form"] == _RUNNING_MAX,
+                      jnp.maximum(v, F0) + c["kpos1"] * ds,
+                      jnp.maximum(F0 + D, T)))
+        start = jnp.where(unique, first, f_after - ds)
+        free = jnp.where(c["wmask"], f_after[c["wsrc"]], free)
+        s0 = start[c["opos"]]
+        cur = jnp.where(c["cmask"],
+                        jnp.where(c["r5"], s0 + rdma_startup, s0), cur)
+        occupied = jnp.where(c["omask"], (start + ds)[c["opos"]], occupied)
+        return (cur, occupied, free), None
+
+    def rdv_level(x, consts, forms):
+        k = consts["sperm"].shape[1]
+        t, stream = x[:k], x[k:2 * k]
+        free = jnp.concatenate([x[2 * k:], jnp.zeros_like(x[:1])])
+        (_, occupied, free), _ = jax.lax.scan(
+            functools.partial(stage, stream),
+            (t, jnp.zeros_like(t), free), {**consts, "form": forms})
+        return jnp.concatenate([occupied, free[:-1]])
+
+    return jax.jit(rdv_level)
+
+
+def _level_stages(lv) -> list:
+    """``(role, stage)`` per stage of a rendez-vous level, in the order
+    ``_run_rdv`` acquires them: ``r5`` (the RDMA start-up follows),
+    ``src`` (DMA source), ``link`` (one hop position), ``dst`` (DMA
+    destination)."""
+    stages = [("r5", lv.r5), ("src", lv.dsrc)]
+    stages += [("link", st) for st in lv.links]
+    if lv.ddst is not None:
+        stages.append(("dst", lv.ddst))
+    return stages
+
+
+def _level_forms(lv, uni: bool) -> np.ndarray:
+    """Each stage's form, as the staged chain picks it: the running
+    maximum for the R5 occupancy, and for a stream stage whose durations
+    are group-constant when ``uni`` promises column-uniform bytes."""
+    return np.array([
+        _UNIQUE if st.max_group == 1 else
+        _RUNNING_MAX if role == "r5" or (uni and st.pb_uniform) else
+        _MAXPLUS for role, st in _level_stages(lv)], dtype=np.int32)
+
+
+def _level_consts(lv):
+    """The rows one level touches, and its per-stage constants stacked
+    for :func:`_rdv_level_kernel` (see there)."""
+    stages = _level_stages(lv)
+    u_rows = np.unique(np.concatenate([st.rows for _, st in stages]))
+    S, k, u = len(stages), len(lv.r5.rows), len(u_rows)
+    passes = max(len(st.takes) for _, st in stages)
+    c = {"r5": np.zeros(S, dtype=bool),
+         "sperm": np.zeros((S, k), dtype=np.int32),
+         "ridx": np.full((S, k), u, dtype=np.int32),
+         "kpos": np.zeros((S, k, 1)), "kpos1": np.zeros((S, k, 1)),
+         "masks": tuple(np.zeros((S, k - (1 << p), 1), dtype=bool)
+                        for p in range(passes)),
+         "wsrc": np.zeros((S, u + 1), dtype=np.int32),
+         "wmask": np.zeros((S, u + 1, 1), dtype=bool),
+         "opos": np.zeros((S, k), dtype=np.int32),
+         "omask": np.zeros((S, k, 1), dtype=bool),
+         "cmask": np.zeros((S, k, 1), dtype=bool)}
+    for i, (role, st) in enumerate(stages):
+        m = len(st.rows)
+        sperm = np.arange(m) if st.sperm is None else st.sperm
+        ridx = np.searchsorted(u_rows, st.rows)
+        c["r5"][i] = role == "r5"
+        c["sperm"][i, :m] = sperm
+        c["ridx"][i, :m] = ridx
+        if st.max_group > 1:
+            c["kpos"][i, :m] = st.kpos
+            c["kpos1"][i, :m] = st.kpos1
+            for p, (s, mask) in enumerate(st.takes):
+                c["masks"][p][i, :m - s] = mask
+            wpos = np.flatnonzero(st.last)      # a group's last acquire
+        else:
+            wpos = np.arange(m)
+        c["wsrc"][i, ridx[wpos]] = wpos
+        c["wmask"][i, ridx[wpos]] = True
+        c["opos"][i, sperm] = np.arange(m)
+        c["omask"][i, sperm] = role != "r5"
+        c["cmask"][i, sperm] = role != "dst"
+    return u_rows, c
+
+
 class JaxScanEngine:
     """``jax.jit`` lane of the same scan kernels.
 
     Jitted kernels are cached per shift sequence (the static part of a
-    stage's ``takes``); the ``(k - s, 1)`` mask operands are cached per
-    ``takes`` list identity — the cache holds a reference to the list
-    itself, so a recycled ``id()`` can never alias a dead stage.  Inputs
+    stage's ``takes``).  A per-stage kernel's ``(k - s, 1)`` mask
+    operands are kept on the host, cached per ``takes`` list identity,
+    and sent to the device with every call; the cache holds a reference
+    to the list itself, so a recycled ``id()`` can never alias a dead
+    stage.  A fused level's constants (:meth:`rdv_level`) live on the
+    device instead, held weakly per level object: they go when the level
+    goes, so programs compiled per call leave nothing behind.  Inputs
     and outputs are NumPy arrays: conversion happens at this boundary
-    only, and the surrounding gather/scatter bookkeeping stays NumPy
-    either way.
+    only.
 
-    ``dispatches`` counts kernel calls per ``(kernel, shifts, (k,
-    columns))`` — each key is one compiled program, so a cold process
-    pays one compile per key — and ``devices`` collects the devices the
-    kernels' outputs lived on (read once per key: one compiled program
-    always runs where it first ran).  ``bytes_in`` sums the ``nbytes`` of
-    every operand a kernel call receives, masks included, and
-    ``bytes_out`` those of every output fetched back: what crosses the
-    host-device link.
+    ``dispatches`` counts kernel calls per ``(kernel, static signature,
+    output shape)`` — each key is one compiled program, so a cold
+    process pays one compile per key — and ``devices`` collects the
+    devices the kernels' outputs lived on (read once per key: one
+    compiled program always runs where it first ran).  ``bytes_in`` sums
+    the ``nbytes`` of every operand a kernel call sends, masks included,
+    and of a level's device constants when they are put there;
+    ``bytes_out`` sums those of every output fetched back: what crosses
+    the host-device link.  ``levels_fused`` and ``levels_staged`` count
+    the rendez-vous levels the transport ran as one :meth:`rdv_level`
+    dispatch and as a chain of per-stage kernels.
 
-    Each call is a span ``scan.maxplus`` or ``scan.running_max``
-    (:mod:`repro.runtime.spans`) holding ``scan.call`` (the operands'
-    transfer and the launch) and ``scan.fetch`` (waiting for the device
-    and copying the outputs back).
+    Each call is a span ``scan.maxplus`` (a fused level is a max-plus
+    program too) or ``scan.running_max`` (:mod:`repro.runtime.spans`)
+    holding ``scan.call`` (the operands' transfer and the launch) and
+    ``scan.fetch`` (waiting for the device and copying the outputs
+    back).  A fused level looks up its constants, stacks its operand
+    and writes the free times back outside its span: that host work is
+    the transport's, as the staged chain's gathers and scatters are.
     """
 
     name = "jax"
 
     def __init__(self):
         self._takes_cache: dict = {}
+        self._level_cache = weakref.WeakKeyDictionary()
         self.dispatches: collections.Counter = collections.Counter()
         self.devices: set = set()
         self.bytes_in = 0
         self.bytes_out = 0
+        self.levels_fused = 0
+        self.levels_staged = 0
+
+    @property
+    def fuses_levels(self) -> bool:
+        """Whether :meth:`rdv_level` may stand in for the per-stage
+        kernels: only while they are this class's own.  A subclass or a
+        patch that replaces :meth:`maxplus_scan` or :meth:`running_max`
+        (a float32 control, a planted fault) keeps the staged chain, so
+        the replacement runs."""
+        cls = type(self)
+        return (cls.maxplus_scan is _OWN_STAGE_KERNELS[0]
+                and cls.running_max is _OWN_STAGE_KERNELS[1])
 
     def _prep(self, takes):
         key = id(takes)
@@ -138,8 +301,8 @@ class JaxScanEngine:
             ent = self._takes_cache[key] = (takes, shifts, masks)
         return ent[1], ent[2]
 
-    def _record(self, kernel: str, shifts: tuple, out):
-        key = (kernel, shifts, out.shape)
+    def _record(self, kernel: str, static: tuple, out):
+        key = (kernel, static, out.shape)
         if key not in self.dispatches:
             self.devices.update(out.devices())
         self.dispatches[key] += 1
@@ -183,6 +346,59 @@ class JaxScanEngine:
                     v = np.asarray(out)
             self.bytes_out += v.nbytes
             return v.reshape(shape)
+
+    def _level(self, lv, uni: bool):
+        """The rows ``lv`` touches, its constants and its forms for
+        ``uni`` on the device (put there on first use), and its shape."""
+        ent = self._level_cache.get(lv)
+        if ent is None:
+            u_rows, consts = _level_consts(lv)
+            self.bytes_in += _nbytes(jax.tree_util.tree_leaves(consts))
+            with jax.enable_x64(True):
+                dev = jax.device_put(consts)
+            S, k = consts["sperm"].shape
+            static = (S, k, len(u_rows), len(consts["masks"]))
+            ent = self._level_cache[lv] = (u_rows, dev, static, {})
+        u_rows, dev, static, forms = ent
+        if uni not in forms:
+            f = _level_forms(lv, uni)
+            self.bytes_in += f.nbytes
+            forms[uni] = jax.device_put(f)
+        return u_rows, dev, forms[uni], static
+
+    def rdv_level(self, state, lv, t, stream, uni: bool, r5_occ: float,
+                  rdma_startup: float):
+        """One rendez-vous level's acquire chain as one dispatch.
+
+        ``t`` is each send's R5 issue time (issue plus handshake) and
+        ``stream`` its stream duration, both ``(k, *batch)``; ``uni``
+        lets a stage whose durations are group-constant take the
+        running-max form, as in the staged chain.  Advances
+        ``state.free`` over the level's rows and returns the time each
+        send's stream ends (its completion less the hop latency)."""
+        u_rows, consts, forms, static = self._level(lv, uni)
+        shape = t.shape
+        k, u = shape[0], len(u_rows)
+        x = np.concatenate([
+            t.reshape(k, -1),
+            np.broadcast_to(stream, shape).reshape(k, -1),
+            state.free[u_rows].reshape(u, -1)])
+        self.bytes_in += x.nbytes
+        with span("scan.maxplus"), jax.enable_x64(True):
+            kernel = _rdv_level_kernel(float(r5_occ), float(rdma_startup))
+            with span("scan.call"):
+                out = kernel(x, consts, forms)
+            self._record("rdv_level", static, out)
+            with span("scan.fetch"):
+                out = np.asarray(out)
+        self.bytes_out += out.nbytes
+        state.free[u_rows] = out[k:].reshape((u,) + shape[1:])
+        self.levels_fused += 1
+        return out[:k].reshape(shape)
+
+
+#: the per-stage kernels a fused level stands in for (``fuses_levels``)
+_OWN_STAGE_KERNELS = (JaxScanEngine.maxplus_scan, JaxScanEngine.running_max)
 
 
 def _nbytes(arrays) -> int:

@@ -1,4 +1,4 @@
-"""Test-session guards + CI known-failure handling.
+"""Test-session guards, CI known-failure handling, shared fixtures.
 
 The dry-run's 512-device flag must NEVER leak into the test session: smoke
 tests and benches see the real single device (multi-device tests spawn
@@ -44,3 +44,17 @@ def pytest_collection_modifyitems(config, items):
         base = item.nodeid.split("[", 1)[0]
         if base in known:
             item.add_marker(mark)
+
+
+@pytest.fixture
+def staged_jax_engine():
+    """A jax scan engine that runs every rendez-vous level as its staged
+    chain of per-stage kernels: its own max-plus kernel re-bound, so
+    ``JaxScanEngine.fuses_levels`` is false."""
+    from repro.core.exanet import scan_engine as se
+
+    class StagedJaxEngine(se.JaxScanEngine):
+        def maxplus_scan(self, D, T, takes):
+            return super().maxplus_scan(D, T, takes)
+
+    return StagedJaxEngine()

@@ -23,7 +23,8 @@ REPLAY_SPANS = {"replay.prepare", "replay.bind", "replay.degrade",
 
 def _replay(eng):
     """Three degraded scenario columns of an HPCG iteration on 16 ranks:
-    both scan kernels, p2p levels, waits and spliced allreduces."""
+    fused rendez-vous levels, the running-max kernel, waits and spliced
+    allreduces."""
     prog = ALL_APPS["hpcg"]().emit_iteration("weak", 16)
     return ExanetMPI().run_program_scenarios(
         prog, compute_scale=np.array([1.0, 1.1, 1.3]),
@@ -101,14 +102,19 @@ def test_a_replay_opens_its_spans_only_while_on(spans_on):
 
 def _counting(factory, seen, kind):
     """Wrap a jitted-kernel factory so that each call adds the ``nbytes``
-    of its operands and outputs to ``seen``."""
-    def make(shifts):
-        kernel = factory(shifts)
+    of its operands and outputs to ``seen``.  Host (NumPy) operands cross
+    with every call; operands already on the device (a fused level's
+    constants) crossed once, when they were put there."""
+    def make(*static):
+        kernel = factory(*static)
 
         def call(*args):
-            *arrays, masks = args
-            seen["in"] += sum(a.nbytes for a in arrays) + \
-                sum(m.nbytes for m in masks)
+            for a in jax.tree_util.tree_leaves(args):
+                if isinstance(a, np.ndarray):
+                    seen["in"] += a.nbytes
+                elif id(a) not in seen["on_device"]:
+                    seen["on_device"].add(id(a))
+                    seen["in"] += a.nbytes
             out = kernel(*args)
             outs = out if isinstance(out, tuple) else (out,)
             seen["out"] += sum(o.nbytes for o in outs)
@@ -118,20 +124,25 @@ def _counting(factory, seen, kind):
     return make
 
 
-def test_jax_engine_counts_the_bytes_each_kernel_call_moves(monkeypatch):
-    seen = {"in": 0, "out": 0, "maxplus": 0, "running_max": 0}
-    monkeypatch.setattr(se, "_maxplus_kernel",
-                        _counting(se._maxplus_kernel, seen, "maxplus"))
-    monkeypatch.setattr(se, "_running_max_kernel",
-                        _counting(se._running_max_kernel, seen,
-                                  "running_max"))
-    eng = se.JaxScanEngine()
-    _replay(eng)
-    assert seen["maxplus"] and seen["running_max"]
-    assert sum(eng.dispatches.values()) == \
-        seen["maxplus"] + seen["running_max"]
-    assert eng.bytes_in == seen["in"] > 0
-    assert eng.bytes_out == seen["out"] > 0
+def test_jax_engine_counts_the_bytes_each_kernel_call_moves(
+        monkeypatch, staged_jax_engine):
+    """Both the fused levels and the staged chain's per-stage kernels."""
+    seen = {}
+    for kind in ("maxplus", "running_max", "rdv_level"):
+        name = "_maxplus_kernel" if kind == "maxplus" else f"_{kind}_kernel"
+        monkeypatch.setattr(se, name,
+                            _counting(getattr(se, name), seen, kind))
+    for eng, kernels in ((se.JaxScanEngine(), ("rdv_level", "running_max")),
+                         (staged_jax_engine, ("maxplus", "running_max"))):
+        seen.update({"in": 0, "out": 0, "maxplus": 0, "running_max": 0,
+                     "rdv_level": 0, "on_device": set()})
+        _replay(eng)
+        assert {k for k in ("maxplus", "running_max", "rdv_level")
+                if seen[k]} == set(kernels)
+        assert sum(eng.dispatches.values()) == \
+            seen["maxplus"] + seen["running_max"] + seen["rdv_level"]
+        assert eng.bytes_in == seen["in"] > 0
+        assert eng.bytes_out == seen["out"] > 0
 
 
 def test_jax_engine_reads_devices_once_per_compiled_program():
