@@ -23,6 +23,14 @@ class MoEConfig:
     #: int8-quantize the all-to-all dispatch payloads (per-slot scales) —
     #: the DeepSeek-V3 fp8-dispatch trick, halving EP wire bytes
     a2a_quant: bool = False
+    #: node-limited routing (DeepSeek-V3 ``noaux_tc``): the experts fall
+    #: into ``n_group`` contiguous groups, a token keeps the ``topk_group``
+    #: groups with the highest sum of their two best scores and picks its
+    #: ``top_k`` experts inside them (1, 1: no limit)
+    n_group: int = 1
+    topk_group: int = 1
+    #: multiplier on the (normalized) combine weights of the routed experts
+    routed_scaling_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,6 +1,7 @@
 """DeepSeek-V3 671B [arXiv:2412.19437]: MLA, 1 shared + 256 routed top-8
-experts, MTP. 61 layers (first 3 dense, d_ff 18432), d_model 7168,
-128 attention heads, expert FFN 2048, vocab 129280."""
+experts (node-limited: 4 of 8 groups), MTP. 61 layers (first 3 dense,
+d_ff 18432), d_model 7168, 128 attention heads, expert FFN 2048, vocab
+129280."""
 from repro.config import ArchConfig, MLAConfig, MoEConfig
 
 ARCH = ArchConfig(
@@ -12,7 +13,10 @@ ARCH = ArchConfig(
                   v_head_dim=128),
     moe=MoEConfig(n_experts=256, top_k=8, d_expert=2048,
                   n_shared_experts=1, d_shared=2048,
-                  router_softmax=False),  # V3 uses sigmoid routing
+                  # sigmoid scores, noaux_tc: top-8 within the best 4 of 8
+                  # groups, normalized weights scaled by 2.5
+                  router_softmax=False, n_group=8, topk_group=4,
+                  routed_scaling_factor=2.5),
     n_dense_layers=3, mtp_depth=1,
     rope_theta=10000.0, mlp_act="silu", mlp_gated=True,
 )

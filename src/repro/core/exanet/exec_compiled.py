@@ -167,6 +167,11 @@ class _Level:
     link_rate: np.ndarray | None = None
     link_wire: np.ndarray | None = None
     n_links: np.ndarray | None = None
+    #: (k, S) resource rows each send takes, in acquire order (R5, DMA
+    #: source, link hops, DMA destination; -1 for a stage it skips): set
+    #: on a serial Program level, which runs as one dispatch
+    #: (``JaxScanEngine.rdv_serial``) and has no stages
+    serial: np.ndarray | None = None
 
 
 @dataclasses.dataclass
@@ -192,12 +197,14 @@ class LinkDegrade:
     wire bandwidth (bandwidth-scale axis), ``extra_us`` adds per-link
     one-way latency (latency axis).
 
-    At run time every level's derived constants are recomputed per column
-    with the *same formulas* ``Network.path_metrics`` uses — per-link
-    eager serialization summed, bottleneck wire bandwidth through the
-    §6.1.1 16KB-block RDMA formula, handshake/hop picking up the extra
-    latency — so an all-ones column is bit-identical to the undegraded
-    constants and the interpreter twin agrees to ~1e-9 under degradation.
+    At run time a level's derived constants are recomputed for every
+    (send, column) pair a degraded link of the send touches (the others
+    keep the level's healthy constants), with the *same formulas*
+    ``Network.path_metrics`` uses — per-link eager serialization summed,
+    bottleneck wire bandwidth through the §6.1.1 16KB-block RDMA
+    formula, handshake/hop picking up the extra latency — so an all-ones
+    column is bit-identical to the undegraded constants and the
+    interpreter twin agrees to ~1e-9 under degradation.
     Loopback sends (no links) are AXI-bound and keep their base constants.
     """
 
@@ -236,25 +243,54 @@ class LinkDegrade:
             return out
         mask = ids >= 0                                    # (k, L)
         idx = np.where(mask, ids, 0)
-        s = self.slow[idx]                                 # (k, L, N)
-        ex = np.where(mask[..., None], self.extra[idx], 0.0)
-        exsum = ex.sum(axis=1)                             # (k, N)
-        rate = np.where(mask[..., None], lv.link_rate[..., None], np.inf)
+        # the level's healthy constants, then the formulas again for the
+        # (send, column) pairs a degraded link of the send touches: the
+        # work follows the faults, not sends x columns
+        one = np.ones(idx.shape + (1,))
+        base = self._derive(lv, slice(None), one, one - 1.0)
+        out = {k: np.repeat(v, self.ncols, axis=1) for k, v in base.items()}
+        hit = (self.slow != 1.0) | (self.extra != 0.0)     # (rows, N)
+        ks, js = np.nonzero((hit[idx] & mask[..., None]).any(axis=1))
+        if ks.size:
+            rows, col = idx[ks], js[:, None]
+            fixed = self._derive(
+                lv, ks, self.slow[rows, col][..., None],
+                np.where(mask[ks], self.extra[rows, col], 0.0)[..., None])
+            for k, v in fixed.items():
+                out[k][ks, js] = v[:, 0]
+        return out
+
+    def _derive(self, lv, sel, s, ex) -> dict:
+        """Sends ``sel`` of level ``lv``: its derived constants, given
+        each link's slowdown ``s`` and extra latency ``ex`` (``(n, L,
+        c)``, ``ex`` 0 on padding); ``(n, c)`` each."""
+        mask = (lv.link_ids[sel] >= 0)[..., None]
+        exsum = _sum_links(ex)                             # (n, c)
+        rate = np.where(mask, lv.link_rate[sel][..., None], np.inf)
         pb = 8.0 / ((rate / s) * 1000.0)   # exactly 0.0 on padding
-        has = (lv.n_links > 0)[:, None]
-        out = {"e_const": lv.e_const + exsum,
-               "eager_pb": np.where(has, pb.sum(axis=1), lv.eager_pb)}
+        has = (lv.n_links[sel] > 0)[:, None]
+        out = {"e_const": lv.e_const[sel] + exsum,
+               "eager_pb": np.where(has, _sum_links(pb), lv.eager_pb[sel])}
         if hasattr(lv, "handshake"):                       # full _Level
-            wire = np.where(mask[..., None],
-                            lv.link_wire[..., None] / s, np.inf)
-            wmin = wire.min(axis=1)                        # (k, N)
+            wire = np.where(mask, lv.link_wire[sel][..., None] / s, np.inf)
+            wmin = wire.min(axis=1)                        # (n, c)
             t_block = self._block_bits / (wmin * 1000.0) + self._gap_us
             bw = self._block_bits / t_block / 1000.0
-            out["handshake"] = lv.handshake + 2.0 * exsum
+            out["handshake"] = lv.handshake[sel] + 2.0 * exsum
             out["stream_pb"] = np.where(has, 8.0 / (bw * 1000.0),
-                                        lv.stream_pb)
-            out["hop"] = lv.hop + exsum
+                                        lv.stream_pb[sel])
+            out["hop"] = lv.hop[sel] + exsum
         return out
+
+
+def _sum_links(a: np.ndarray) -> np.ndarray:
+    """``a`` (n, L, c) summed over its links, in link order (one order
+    whatever the shape, so a healthy pair and a degraded one add up
+    alike)."""
+    out = a[:, 0].copy()
+    for i in range(1, a.shape[1]):
+        out += a[:, i]
+    return out
 
 
 @dataclasses.dataclass
@@ -508,16 +544,8 @@ class VecTransport:
             hop = _deg_col(c["hop"], cols)
             uni = False
         stream = nbl * stream_pb
-        eng = self._eng
-        if hasattr(eng, "rdv_level"):
-            # an unmasked level over every column runs as one dispatch;
-            # masked and column-split levels keep the staged chain
-            if act is None and cols is None and eng.fuses_levels:
-                comp = eng.rdv_level(state, lv, t_issue + handshake, stream,
-                                     uni, self._r5_occ,
-                                     self._rdma_startup) + hop
-                return comp, comp
-            eng.levels_staged += 1
+        if hasattr(self._eng, "levels_staged"):
+            self._eng.levels_staged += 1
         st = lv.r5
         r = self._stage_acquire(state, st, t_issue + handshake,
                                 self._r5_occ, act, True, cols)

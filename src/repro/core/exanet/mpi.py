@@ -892,25 +892,12 @@ class ExanetMPI:
                     raise ValueError(
                         f"t0 must be (nranks, N); got {t0_cols.shape} for "
                         f"nranks={prog.nranks}, N={N}")
-            if (comp_cols is None and post_cols is None and site_cols is None
-                    and (t0_cols is not None or slow_map or extra_map)):
-                # t0-/link-only sweep: bind_arrays infers N from payload
-                # arrays, so hold one of them constant across the N columns
-                if len(base_comp):
-                    comp_cols = np.broadcast_to(
-                        base_comp[:, None], (len(base_comp), N))
-                elif len(base_post):
-                    post_cols = np.broadcast_to(
-                        base_post[:, None], (len(base_post), N))
-                else:
-                    site_cols = np.broadcast_to(
-                        np.array(base[2], dtype=np.int64)[:, None],
-                        (len(base_site), N))
             plans = self._plan_program_sites(prog, plans)
             art = self.program_artifact(prog)
+        # a t0-/link-only sweep binds the base payload to its N columns
         bound = art.bind_arrays(prog, compute_us=comp_cols,
                                 post_nbytes=post_cols,
-                                site_nbytes=site_cols, plans=plans)
+                                site_nbytes=site_cols, plans=plans, ncols=N)
         # build the degradation AFTER binding: the bind's probe is what
         # allocates the engine's LINK resource ids on a cold artifact
         deg = self._link_degrade(slow_map, extra_map, N) \

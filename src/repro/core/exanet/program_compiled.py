@@ -230,6 +230,9 @@ class _Static:
                 self.event_of_post[rp] = (e, False)
                 ids.append(e)
             self.chan_events[key] = ids
+        #: (2, n_events): each event's send post and recv post
+        self.event_posts = np.array(self.events, dtype=np.int64).reshape(
+            -1, 2).T
         # item -> segment bookkeeping for the bind-time offset cumsum
         # (items of one segment are contiguous and gids increase in walk
         # order, so segmented prefixes come from plain cumsum + gathers)
@@ -270,7 +273,12 @@ class _Static:
 
 def extract_data(prog: Program) -> tuple:
     """The bindable payload of a program, in static-walk order:
-    (compute us, post nbytes, per-site collective nbytes)."""
+    (compute us, post nbytes, per-site collective nbytes); walked once
+    per program object (:meth:`Program.memo`)."""
+    return prog.memo("_payload", lambda: _extract_data(prog))
+
+
+def _extract_data(prog: Program) -> tuple:
     comp: list[float] = []
     post_nb: list[int] = []
     site_nb: dict[int, int] = {}
@@ -399,10 +407,25 @@ class _LevelPlan:
     coll: _CollSlot | None = None
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class _LoweredTape:
-    levels: list
-    n_rows: int
+    """The level plans of one tape; ``build`` makes them on first use and
+    returns them with the engine's resource-row count after."""
+    build: object               # () -> (list[_LevelPlan], n_rows)
+    #: the same tape in serial levels, for engines that run them
+    serial: "_LoweredTape | None" = None
+    _plan: tuple | None = None
+
+    @property
+    def levels(self) -> list:
+        if self._plan is None:
+            self._plan, self.build = self.build(), None
+        return self._plan[0]
+
+    @property
+    def n_rows(self) -> int:
+        self.levels
+        return self._plan[1]
 
 
 @dataclasses.dataclass
@@ -423,9 +446,48 @@ class _BoundIR:
     post_off: np.ndarray        # (n_posts, B) in-segment clock offsets
     seg_total: np.ndarray       # (n_segs, B)
     rank_compute: np.ndarray    # (nranks, B)
-    levels: list                # _BoundLevel per _LevelPlan (None w/o p2p)
     site_sizes: list            # per site: tuple of per-column nbytes
     coll_entry_off: dict        # async site idx -> (nranks, B) item offsets
+    post_nb: np.ndarray         # (n_posts, B) post bytes
+    eager_max: int
+    _levels: list | None = None
+    _serial: tuple | None = None
+
+    @property
+    def levels(self) -> list:
+        """A :class:`_BoundLevel` per stage-major level (bound on first
+        use: an engine that runs the serial levels never needs them)."""
+        if self._levels is None:
+            self._levels = _bind_levels(self.lowered, self.post_nb,
+                                        self.eager_max)
+        return self._levels
+
+    @property
+    def serial(self) -> tuple | None:
+        """``(serial lowered tape, its _BoundLevels)`` where every send of
+        this binding is rendez-vous, else None (bound on first use)."""
+        if self._serial is None:
+            lt = self.lowered.serial
+            levels = _bind_levels(lt, self.post_nb, self.eager_max)
+            rdv = not any(bl.any_e for bl in levels if bl is not None)
+            self._serial = (lt, levels) if rdv else ()
+        return self._serial or None
+
+
+def _bind_levels(lowered: _LoweredTape, post_nb, eager_max: int) -> list:
+    """A :class:`_BoundLevel` per level of ``lowered`` (None without
+    p2p)."""
+    b_levels = []
+    for plan in lowered.levels:
+        if plan.p2p is None:
+            b_levels.append(None)
+            continue
+        nb = post_nb[plan.p2p.send_post]
+        is_rdv = nb > eager_max
+        b_levels.append(_BoundLevel(
+            nb=nb, is_rdv=is_rdv, any_e=bool((~is_rdv).any()),
+            any_r=bool(is_rdv.any()), uni=bool((nb == nb[:1]).all())))
+    return b_levels
 
 
 class CompiledProgram(VecTransport):
@@ -480,8 +542,22 @@ class CompiledProgram(VecTransport):
         lt = self._tape_cache.get(tape)
         if lt is not None:
             return lt
-        st = self._static
         pm, res_tags = self._event_metrics()
+        lt = self._levels(tape, pm, res_tags)
+        lt.serial = self._levels(tape, pm, None)
+        # lowering the collectives registers their links' resource rows:
+        # do it now, before a run sizes its state and link axes by them
+        lt.serial.levels
+        self._tape_cache[tape] = lt
+        return lt
+
+    def _levels(self, tape: tuple, pm, res_tags) -> _LoweredTape:
+        """The lowered tape; its plans are made on first use.  With
+        ``res_tags`` (each event's (row, stage) pairs) the levels are
+        stage-major; with None only the clock dependencies (waits,
+        collectives) split them, and every p2p level is serial (its sends
+        take their rows one after another in tape order)."""
+        st = self._static
         avail: dict[int, int] = {g: 0 for g in st.first_gid}
         ev_level: dict[int, int] = {}
         wait_level: dict[int, int] = {}
@@ -553,7 +629,8 @@ class CompiledProgram(VecTransport):
                 sp, rp = st.events[e]
                 lv = max(floor, resolve_seg(st.posts[sp].gid),
                          resolve_seg(st.posts[rp].gid))
-                for (row, tag) in res_tags[e]:
+                uses = res_tags[e] if res_tags is not None else ()
+                for (row, tag) in uses:
                     tags = row_tags.get(row)
                     if tags:
                         o = stage_ord(tag)
@@ -563,7 +640,7 @@ class CompiledProgram(VecTransport):
                             if need > lv:
                                 lv = need
                 ev_level[e] = lv
-                for (row, tag) in res_tags[e]:
+                for (row, tag) in uses:
                     d = row_tags.setdefault(row, {})
                     if d.get(tag, -1) < lv:
                         d[tag] = lv
@@ -596,28 +673,32 @@ class CompiledProgram(VecTransport):
             [lv for lv in ev_level.values()]
             + [lv for lv in wait_level.values()]
             + [lv for lv in coll_level.values()] + [-1])
-        levels = [_LevelPlan() for _ in range(n_levels)]
         by_level: dict[int, list[int]] = {}
         for item in tape:                      # keep tape order per level
             if item[0] == "p":
                 by_level.setdefault(ev_level[item[1]], []).append(item[1])
-        for lv_i, evs in by_level.items():
-            levels[lv_i].p2p = self._lower_p2p_level(evs, pm)
-        waits_by_level: dict[int, list[_WaitNode]] = {}
-        for w in st.waits:
-            waits_by_level.setdefault(wait_level[w.idx], []).append(w)
-        for lv_i, ws in waits_by_level.items():
-            levels[lv_i].waits = self._lower_waits(ws)
-        for item in tape:
-            if item[0] == "x":
-                _, s, name = item
-                levels[coll_level[s]].coll = self._lower_coll(
-                    st.sites[s], name)
-        lt = _LoweredTape(levels, self._mpi.net.engine.n_resource_ids)
-        self._tape_cache[tape] = lt
-        return lt
 
-    def _lower_p2p_level(self, evs: list[int], pm) -> _PLevel:
+        def build() -> list:
+            levels = [_LevelPlan() for _ in range(n_levels)]
+            for lv_i, evs in by_level.items():
+                levels[lv_i].p2p = self._lower_p2p_level(evs, pm,
+                                                         res_tags is None)
+            waits_by_level: dict[int, list[_WaitNode]] = {}
+            for w in st.waits:
+                waits_by_level.setdefault(wait_level[w.idx], []).append(w)
+            for lv_i, ws in waits_by_level.items():
+                levels[lv_i].waits = self._lower_waits(ws)
+            for item in tape:
+                if item[0] == "x":
+                    _, s, name = item
+                    levels[coll_level[s]].coll = self._lower_coll(
+                        st.sites[s], name)
+            return levels, self._mpi.net.engine.n_resource_ids
+
+        return _LoweredTape(build)
+
+    def _lower_p2p_level(self, evs: list[int], pm,
+                         serial: bool = False) -> _PLevel:
         st = self._static
         idx = np.array(evs, dtype=np.int64)
         k = len(idx)
@@ -625,12 +706,26 @@ class CompiledProgram(VecTransport):
         spb = pm["stream_us_per_byte"][idx]
         n_links = pm["n_links"][idx]
         max_links = int(n_links.max()) if k else 0
-        link_stages = []
-        for pos_k in range(max_links):
-            sub = np.flatnonzero(n_links > pos_k)
-            link_stages.append(_make_stage(
-                pos[sub], pm["link_ids"][idx[sub], pos_k], spb[sub]))
-        ddst_sub = np.flatnonzero(pm["dma_dst_id"][idx] >= 0)
+        stages = {"pktz": None, "r5": None, "dsrc": None, "links": [],
+                  "ddst": None, "serial": None}
+        if serial:
+            stages["serial"] = np.column_stack([
+                pm["r5_id"][idx], pm["dma_src_id"][idx],
+                pm["link_ids"][idx][:, :max_links], pm["dma_dst_id"][idx]])
+        else:
+            link_stages = []
+            for pos_k in range(max_links):
+                sub = np.flatnonzero(n_links > pos_k)
+                link_stages.append(_make_stage(
+                    pos[sub], pm["link_ids"][idx[sub], pos_k], spb[sub]))
+            ddst_sub = np.flatnonzero(pm["dma_dst_id"][idx] >= 0)
+            stages.update(
+                pktz=_make_stage(pos, pm["pktz_id"][idx], span=k),
+                r5=_make_stage(pos, pm["r5_id"][idx], span=k),
+                dsrc=_make_stage(pos, pm["dma_src_id"][idx], spb, span=k),
+                links=[s for s in link_stages if s is not None],
+                ddst=_make_stage(ddst_sub, pm["dma_dst_id"][idx[ddst_sub]],
+                                 spb[ddst_sub]))
         lv = _Level(
             sel=idx,
             e_const=pm["eager_ow_const_us"][idx][:, None],
@@ -638,17 +733,11 @@ class CompiledProgram(VecTransport):
             handshake=pm["handshake_ow_us"][idx][:, None],
             stream_pb=spb[:, None],
             hop=pm["hop_latency_us"][idx][:, None],
-            pktz=_make_stage(pos, pm["pktz_id"][idx], span=k),
-            r5=_make_stage(pos, pm["r5_id"][idx], span=k),
-            dsrc=_make_stage(pos, pm["dma_src_id"][idx], spb, span=k),
-            links=[s for s in link_stages if s is not None],
-            ddst=_make_stage(ddst_sub, pm["dma_dst_id"][idx[ddst_sub]],
-                             spb[ddst_sub]),
             src_ranks=None, dst_perm=None, dst_starts=None, udst=None,
             link_ids=pm["link_ids"][idx],
             link_rate=pm["link_rate_gbps"][idx],
             link_wire=pm["link_wire_gbps"][idx],
-            n_links=n_links)
+            n_links=n_links, **stages)
         send_post = np.array([st.events[e][0] for e in evs], dtype=np.int64)
         recv_post = np.array([st.events[e][1] for e in evs], dtype=np.int64)
         return _PLevel(
@@ -778,7 +867,7 @@ class CompiledProgram(VecTransport):
     @spans.traced("replay.bind")
     def bind_arrays(self, prog: Program, *, compute_us=None,
                     post_nbytes=None, site_nbytes=None,
-                    plans=None) -> _BoundIR:
+                    plans=None, ncols: int = 1) -> _BoundIR:
         """Scenario binding: N payload perturbations of one base program
         as batch columns, *without* materializing N Program objects or
         probing N times.
@@ -788,7 +877,9 @@ class CompiledProgram(VecTransport):
         order :func:`extract_data` emits), ``post_nbytes``
         (n_posts, N) per-post byte counts, ``site_nbytes`` (n_sites, N)
         per-collective-site byte counts; ``None`` holds the base
-        program's value constant across columns.
+        program's value constant across columns.  With none of the three
+        given, the base payload binds to ``ncols`` columns, laid out once
+        (a sweep over entry clocks or link faults alone).
 
         All columns share the *base binding's* probe tape.  That is exact
         whenever the scheduler's firing order is payload-invariant —
@@ -822,7 +913,8 @@ class CompiledProgram(VecTransport):
                 N = a.shape[1]
             elif a.shape[1] != N:
                 raise ValueError("scenario arrays disagree on N")
-        if N is None:
+        uniform = N is None
+        if uniform:
             N = 1
         comp_cols = (np.asarray(compute_us, dtype=np.float64)
                      if compute_us is not None else np.broadcast_to(
@@ -858,7 +950,8 @@ class CompiledProgram(VecTransport):
         lowered = self._lowered(tape)
         site_sizes = [tuple(int(x) for x in site_cols[j])
                       for j in range(len(st.sites))]
-        return self._bind_cols(lowered, comp_cols, post_nb, site_sizes)
+        return self._bind_cols(lowered, comp_cols, post_nb, site_sizes,
+                               ncols if uniform else None)
 
     def _bind_data(self, lowered: _LoweredTape, datas: list) -> _BoundIR:
         st = self._static
@@ -872,9 +965,11 @@ class CompiledProgram(VecTransport):
         return self._bind_cols(lowered, comp_cols, post_nb, site_sizes)
 
     def _bind_cols(self, lowered: _LoweredTape, comp_cols: np.ndarray,
-                   post_nb: np.ndarray, site_sizes: list) -> _BoundIR:
+                   post_nb: np.ndarray, site_sizes: list,
+                   ncols: int | None = None) -> _BoundIR:
         """Column-stacked payload arrays -> a :class:`_BoundIR` (shared
-        tail of :meth:`bind` and :meth:`bind_arrays`)."""
+        tail of :meth:`bind` and :meth:`bind_arrays`).  ``ncols`` binds
+        one payload column (the arrays hold one) to that many columns."""
         st = self._static
         B = comp_cols.shape[1]
         po = self._p.a53_call_overhead_us
@@ -898,26 +993,27 @@ class CompiledProgram(VecTransport):
         coll_entry_off = {
             s.idx: item_off[np.array(s.entry_item, dtype=np.int64)]
             for s in st.sites if s.handle is not None}
-        b_levels = []
-        for plan in lowered.levels:
-            if plan.p2p is None:
-                b_levels.append(None)
-                continue
-            nb = post_nb[plan.p2p.send_post]
-            # the interpreter's _match rejects size-mismatched channels;
-            # re-bound programs must fail the same way (the probe already
-            # raised for the compiled columns, this guards the arrays)
-            nb_r = post_nb[plan.p2p.recv_post]
-            if not np.array_equal(nb, nb_r):
-                raise ProgramError(
-                    "size mismatch on a matched (src, dst, tag) channel")
-            is_rdv = nb > self._eager_max
-            b_levels.append(_BoundLevel(
-                nb=nb, is_rdv=is_rdv, any_e=bool((~is_rdv).any()),
-                any_r=bool(is_rdv.any()),
-                uni=bool((nb == nb[:1]).all())))
+        # the interpreter's _match rejects size-mismatched channels;
+        # re-bound programs must fail the same way (the probe already
+        # raised for the compiled columns, this guards the arrays)
+        if not np.array_equal(post_nb[st.event_posts[0]],
+                              post_nb[st.event_posts[1]]):
+            raise ProgramError(
+                "size mismatch on a matched (src, dst, tag) channel")
+        if ncols is not None and ncols != B:
+            # a column-uniform payload was laid out once: every column
+            # reads the same rows
+            def cols(a):
+                return np.broadcast_to(a, (a.shape[0], ncols))
+            post_off, seg_total, rank_compute, post_nb = (
+                cols(post_off), cols(seg_total), cols(rank_compute),
+                cols(post_nb))
+            coll_entry_off = {i: cols(a) for i, a in coll_entry_off.items()}
+            site_sizes = [s * ncols for s in site_sizes]
+            B = ncols
         return _BoundIR(B, lowered, post_off, seg_total, rank_compute,
-                        b_levels, site_sizes, coll_entry_off)
+                        site_sizes, coll_entry_off, post_nb,
+                        self._eager_max)
 
     # ------------------------------------------------------------ execution
     def run(self, bound: _BoundIR, *, engine=None, t0=None,
@@ -947,7 +1043,14 @@ class CompiledProgram(VecTransport):
             B = bound.B
             if deg is not None and deg.ncols not in (1, B):
                 raise ValueError(f"deg has {deg.ncols} columns, batch has {B}")
-            lowered = bound.lowered
+            # serial levels stand in for the per-stage kernels only while
+            # those are the engine's own (``fuses_levels``): a replaced
+            # kernel keeps the stage-major levels, so the replacement runs
+            if getattr(self._eng, "fuses_levels", False) and \
+                    bound.serial is not None:
+                lowered, b_levels = bound.serial
+            else:
+                lowered, b_levels = bound.lowered, bound.levels
             state = ResourceState(lowered.n_rows, B)
             C = np.zeros((st.n_segs, B))
             if t0 is not None:
@@ -965,7 +1068,7 @@ class CompiledProgram(VecTransport):
             n_rows = len(st.events) + st.n_async * self.nranks
             send_done = np.empty((n_rows, B))
             recv_done = np.empty((n_rows, B))
-            for plan, bl in zip(lowered.levels, bound.levels):
+            for plan, bl in zip(lowered.levels, b_levels):
                 if plan.p2p is not None:
                     self._exec_p2p_level(state, plan.p2p, bl, C, bound,
                                          send_done, recv_done)
@@ -989,6 +1092,12 @@ class CompiledProgram(VecTransport):
         t_send = C[pl.send_seg] + bound.post_off[pl.send_post]
         t_recv = C[pl.recv_seg] + bound.post_off[pl.recv_post]
         lv, nb = pl.lv, bl.nb
+        if lv.serial is not None:         # every send rendez-vous (bind)
+            comp = self._run_serial(state, lv, np.maximum(t_send, t_recv),
+                                    nb)
+            send_done[pl.ev] = comp
+            recv_done[pl.ev] = comp
+            return
         if not bl.any_r:
             comp, sfree = self._run_eager(state, lv, t_send, nb, None, None)
             send_done[pl.ev] = sfree
@@ -1008,6 +1117,19 @@ class CompiledProgram(VecTransport):
         send_done[pl.ev] = np.where(bl.is_rdv, comp_r, sfree_e)
         recv_done[pl.ev] = np.where(bl.is_rdv, comp_r,
                                     np.maximum(comp_e, t_recv))
+
+    def _run_serial(self, state, lv, t_issue, nbl):
+        """A serial level's rendez-vous transfers, as one engine call:
+        the completion of each."""
+        if self._deg is None:
+            handshake, stream_pb, hop = lv.handshake, lv.stream_pb, lv.hop
+        else:
+            c = self._deg.consts(lv)
+            handshake, stream_pb, hop = c["handshake"], c["stream_pb"], \
+                c["hop"]
+        return self._eng.rdv_serial(state, lv, t_issue + handshake,
+                                    nbl * stream_pb, self._r5_occ,
+                                    self._rdma_startup) + hop
 
     @spans.traced("transport.waits")
     def _exec_waits(self, wp: _WaitPlan, C, bound, send_done,

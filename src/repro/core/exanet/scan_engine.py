@@ -32,13 +32,13 @@ static per stage (they key the jitted kernel cache), masks are traced
 operands.  The per-stage kernels keep their masks on the host and send
 them with every call.
 
-The jax engine also runs a whole rendez-vous level's acquire chain
-(R5 → DMA source → link hops → DMA destination) as one dispatch,
-:meth:`JaxScanEngine.rdv_level`: the level's index arrays and masks are
-put on the device once per level object and stay there, and each call
-sends only the chain's issue times, stream durations and the free times
-of the rows the level touches.  The transport hands it a level whenever
-the level runs unmasked over every column (DESIGN.md §2.5).
+The jax engine also runs a whole rendez-vous level of a compiled
+program as one dispatch, :meth:`JaxScanEngine.rdv_serial`: each send
+takes its rows (R5 → DMA source → link hops → DMA destination) one after
+another, send after send, one scan step per send.  The level's row table
+is put on the device once per level object and stays there, and each
+call sends only the issue times, stream durations and the free times of
+the rows the level touches (DESIGN.md §2.5).
 """
 
 from __future__ import annotations
@@ -104,134 +104,54 @@ def _running_max_kernel(shifts: tuple):
     return jax.jit(running_max)
 
 
-#: per-stage forms of a level program: no row repeats, group-constant
-#: durations (one running maximum), or the general max-plus scan
-_UNIQUE, _RUNNING_MAX, _MAXPLUS = 0, 1, 2
-
-
 @functools.lru_cache(maxsize=None)
-def _rdv_level_kernel(r5_occ: float, rdma_startup: float):
-    """Jitted acquire chain of one rendez-vous level (``jit_rdv_level``):
-    the stages of ``VecTransport._run_rdv`` in order, with the same
-    arithmetic, as one ``lax.scan`` over stages so the program holds one
-    stage body whatever the level's depth.
+def _rdv_serial_kernel(r5_occ: float, rdma_startup: float):
+    """Jitted serial rendez-vous level (``jit_rdv_serial``): each send
+    takes its rows (R5, DMA source, link hops, DMA destination) one after
+    another, send after send in level order, as the interpreter's engine
+    does; one ``lax.scan`` step per send.  Sends may share rows across
+    stages in any order, so a level is split only by clock dependencies.
 
-    The operand ``x`` stacks the issue times ``t`` (k rows), the stream
-    durations (k rows) and the free times of the level's u rows; the
-    output stacks the times each send's stream ends (k rows) and the
-    rows' new free times.  ``consts`` holds per stage (leading axis) the
-    level positions it gathers (padded to k, extra acquires inert: they
-    read a spare row, their masks are off and nothing reads them back),
-    each acquire's row among the u, combine masks, within-group ordinals,
-    which rows it writes back from which acquire, and where its starts
-    land in level order; ``forms`` holds each stage's form.  A row may be
-    acquired by several stages (a program level lets a later send take a
-    row at a later stage), so each stage reads the free times the stages
-    before it wrote."""
+    ``x`` stacks the issue times (k rows), the stream durations (k rows)
+    and the free times of the level's u rows; ``consts`` holds each
+    send's rows among the u (``rows``, (k, S); a stage the send does not
+    take reads the spare row u) and which of its stages it takes
+    (``valid``).  The output stacks each send's stream end and the rows'
+    new free times."""
 
-    def stage(stream, carry, c):
-        cur, occupied, free = carry
-        sperm = c["sperm"]
-        ts = cur[sperm]
-        ds = jnp.where(c["r5"], r5_occ, stream[sperm])
-        F0 = free[c["ridx"]]
-        v = ts - c["kpos"] * ds
-        D, T = ds, ts + ds
-        for p, m in enumerate(c["masks"]):
-            s = 1 << p
-            v = v.at[s:].set(jnp.where(m, jnp.maximum(v[:-s], v[s:]),
-                                       v[s:]))
-            T = T.at[s:].set(jnp.where(
-                m, jnp.maximum(T[:-s] + D[s:], T[s:]), T[s:]))
-            D = D.at[s:].set(jnp.where(m, D[:-s] + D[s:], D[s:]))
-        unique = c["form"] == _UNIQUE
-        first = jnp.maximum(ts, F0)
-        f_after = jnp.where(
-            unique, first + ds,
-            jnp.where(c["form"] == _RUNNING_MAX,
-                      jnp.maximum(v, F0) + c["kpos1"] * ds,
-                      jnp.maximum(F0 + D, T)))
-        start = jnp.where(unique, first, f_after - ds)
-        free = jnp.where(c["wmask"], f_after[c["wsrc"]], free)
-        s0 = start[c["opos"]]
-        cur = jnp.where(c["cmask"],
-                        jnp.where(c["r5"], s0 + rdma_startup, s0), cur)
-        occupied = jnp.where(c["omask"], (start + ds)[c["opos"]], occupied)
-        return (cur, occupied, free), None
-
-    def rdv_level(x, consts, forms):
-        k = consts["sperm"].shape[1]
+    def rdv_serial(x, consts):
+        rows, valid = consts["rows"], consts["valid"]
+        k, n_stages = rows.shape
         t, stream = x[:k], x[k:2 * k]
         free = jnp.concatenate([x[2 * k:], jnp.zeros_like(x[:1])])
-        (_, occupied, free), _ = jax.lax.scan(
-            functools.partial(stage, stream),
-            (t, jnp.zeros_like(t), free), {**consts, "form": forms})
-        return jnp.concatenate([occupied, free[:-1]])
 
-    return jax.jit(rdv_level)
+        def send(free, ev):
+            r, ok, te, de = ev
+            F = free[r]
+            start = jnp.maximum(te, F[0])             # R5, then start-up
+            new = [start + r5_occ]
+            cur = end = start + rdma_startup
+            for i in range(1, n_stages):              # DMA src, hops, dst
+                start = jnp.maximum(cur, F[i])
+                new.append(jnp.where(ok[i], start + de, F[i]))
+                cur = jnp.where(ok[i], start, cur)
+                end = jnp.where(ok[i], start + de, end)
+            return free.at[r].set(jnp.stack(new)), end
 
+        free, ends = jax.lax.scan(send, free, (rows, valid, t, stream))
+        return jnp.concatenate([ends, free[:-1]])
 
-def _level_stages(lv) -> list:
-    """``(role, stage)`` per stage of a rendez-vous level, in the order
-    ``_run_rdv`` acquires them: ``r5`` (the RDMA start-up follows),
-    ``src`` (DMA source), ``link`` (one hop position), ``dst`` (DMA
-    destination)."""
-    stages = [("r5", lv.r5), ("src", lv.dsrc)]
-    stages += [("link", st) for st in lv.links]
-    if lv.ddst is not None:
-        stages.append(("dst", lv.ddst))
-    return stages
-
-
-def _level_forms(lv, uni: bool) -> np.ndarray:
-    """Each stage's form, as the staged chain picks it: the running
-    maximum for the R5 occupancy, and for a stream stage whose durations
-    are group-constant when ``uni`` promises column-uniform bytes."""
-    return np.array([
-        _UNIQUE if st.max_group == 1 else
-        _RUNNING_MAX if role == "r5" or (uni and st.pb_uniform) else
-        _MAXPLUS for role, st in _level_stages(lv)], dtype=np.int32)
+    return jax.jit(rdv_serial)
 
 
-def _level_consts(lv):
-    """The rows one level touches, and its per-stage constants stacked
-    for :func:`_rdv_level_kernel` (see there)."""
-    stages = _level_stages(lv)
-    u_rows = np.unique(np.concatenate([st.rows for _, st in stages]))
-    S, k, u = len(stages), len(lv.r5.rows), len(u_rows)
-    passes = max(len(st.takes) for _, st in stages)
-    c = {"r5": np.zeros(S, dtype=bool),
-         "sperm": np.zeros((S, k), dtype=np.int32),
-         "ridx": np.full((S, k), u, dtype=np.int32),
-         "kpos": np.zeros((S, k, 1)), "kpos1": np.zeros((S, k, 1)),
-         "masks": tuple(np.zeros((S, k - (1 << p), 1), dtype=bool)
-                        for p in range(passes)),
-         "wsrc": np.zeros((S, u + 1), dtype=np.int32),
-         "wmask": np.zeros((S, u + 1, 1), dtype=bool),
-         "opos": np.zeros((S, k), dtype=np.int32),
-         "omask": np.zeros((S, k, 1), dtype=bool),
-         "cmask": np.zeros((S, k, 1), dtype=bool)}
-    for i, (role, st) in enumerate(stages):
-        m = len(st.rows)
-        sperm = np.arange(m) if st.sperm is None else st.sperm
-        ridx = np.searchsorted(u_rows, st.rows)
-        c["r5"][i] = role == "r5"
-        c["sperm"][i, :m] = sperm
-        c["ridx"][i, :m] = ridx
-        if st.max_group > 1:
-            c["kpos"][i, :m] = st.kpos
-            c["kpos1"][i, :m] = st.kpos1
-            for p, (s, mask) in enumerate(st.takes):
-                c["masks"][p][i, :m - s] = mask
-            wpos = np.flatnonzero(st.last)      # a group's last acquire
-        else:
-            wpos = np.arange(m)
-        c["wsrc"][i, ridx[wpos]] = wpos
-        c["wmask"][i, ridx[wpos]] = True
-        c["opos"][i, sperm] = np.arange(m)
-        c["omask"][i, sperm] = role != "r5"
-        c["cmask"][i, sperm] = role != "dst"
-    return u_rows, c
+def _serial_consts(lv):
+    """The rows a serial level touches and its per-send row table."""
+    table = lv.serial
+    valid = table >= 0
+    u_rows = np.unique(table[valid])
+    rows = np.where(valid, np.searchsorted(u_rows, np.where(valid, table, 0)),
+                    len(u_rows)).astype(np.int32)
+    return u_rows, {"rows": rows, "valid": valid}
 
 
 class JaxScanEngine:
@@ -242,8 +162,8 @@ class JaxScanEngine:
     operands are kept on the host, cached per ``takes`` list identity,
     and sent to the device with every call; the cache holds a reference
     to the list itself, so a recycled ``id()`` can never alias a dead
-    stage.  A fused level's constants (:meth:`rdv_level`) live on the
-    device instead, held weakly per level object: they go when the level
+    stage.  A fused level's row table (:meth:`rdv_serial`) lives on the
+    device instead, held weakly per level object: it goes when the level
     goes, so programs compiled per call leave nothing behind.  Inputs
     and outputs are NumPy arrays: conversion happens at this boundary
     only.
@@ -257,8 +177,11 @@ class JaxScanEngine:
     and of a level's device constants when they are put there;
     ``bytes_out`` sums those of every output fetched back: what crosses
     the host-device link.  ``levels_fused`` and ``levels_staged`` count
-    the rendez-vous levels the transport ran as one :meth:`rdv_level`
-    dispatch and as a chain of per-stage kernels.
+    the rendez-vous levels the transport ran as one :meth:`rdv_serial`
+    dispatch and as a chain of per-stage kernels.  ``rdv_level_bytes``
+    sums, per :meth:`rdv_serial` call, its operand, its resident row
+    table and its result: what any implementation of the level has to
+    read and write once.
 
     Each call is a span ``scan.maxplus`` (a fused level is a max-plus
     program too) or ``scan.running_max`` (:mod:`repro.runtime.spans`)
@@ -280,10 +203,11 @@ class JaxScanEngine:
         self.bytes_out = 0
         self.levels_fused = 0
         self.levels_staged = 0
+        self.rdv_level_bytes = 0
 
     @property
     def fuses_levels(self) -> bool:
-        """Whether :meth:`rdv_level` may stand in for the per-stage
+        """Whether :meth:`rdv_serial` may stand in for the per-stage
         kernels: only while they are this class's own.  A subclass or a
         patch that replaces :meth:`maxplus_scan` or :meth:`running_max`
         (a float32 control, a planted fault) keeps the staged chain, so
@@ -347,36 +271,28 @@ class JaxScanEngine:
             self.bytes_out += v.nbytes
             return v.reshape(shape)
 
-    def _level(self, lv, uni: bool):
-        """The rows ``lv`` touches, its constants and its forms for
-        ``uni`` on the device (put there on first use), and its shape."""
+    def _serial(self, lv):
+        """The rows serial level ``lv`` touches, its row table on the
+        device (put there on first use) and the table's bytes."""
         ent = self._level_cache.get(lv)
         if ent is None:
-            u_rows, consts = _level_consts(lv)
-            self.bytes_in += _nbytes(jax.tree_util.tree_leaves(consts))
-            with jax.enable_x64(True):
-                dev = jax.device_put(consts)
-            S, k = consts["sperm"].shape
-            static = (S, k, len(u_rows), len(consts["masks"]))
-            ent = self._level_cache[lv] = (u_rows, dev, static, {})
-        u_rows, dev, static, forms = ent
-        if uni not in forms:
-            f = _level_forms(lv, uni)
-            self.bytes_in += f.nbytes
-            forms[uni] = jax.device_put(f)
-        return u_rows, dev, forms[uni], static
+            u_rows, consts = _serial_consts(lv)
+            nbytes = _nbytes(consts.values())
+            self.bytes_in += nbytes
+            ent = self._level_cache[lv] = (u_rows, jax.device_put(consts),
+                                           nbytes)
+        return ent
 
-    def rdv_level(self, state, lv, t, stream, uni: bool, r5_occ: float,
-                  rdma_startup: float):
-        """One rendez-vous level's acquire chain as one dispatch.
-
-        ``t`` is each send's R5 issue time (issue plus handshake) and
-        ``stream`` its stream duration, both ``(k, *batch)``; ``uni``
-        lets a stage whose durations are group-constant take the
-        running-max form, as in the staged chain.  Advances
-        ``state.free`` over the level's rows and returns the time each
-        send's stream ends (its completion less the hop latency)."""
-        u_rows, consts, forms, static = self._level(lv, uni)
+    def rdv_serial(self, state, lv, t, stream, r5_occ: float,
+                   rdma_startup: float):
+        """A serial level (``lv.serial``) as one dispatch: each send
+        takes its rows one after another, send after send in level
+        order.  ``t`` is each send's R5 issue time (issue plus
+        handshake) and ``stream`` its stream duration, both ``(k,
+        *batch)``.  Advances ``state.free`` over the level's rows and
+        returns the time each send's stream ends (its completion less the
+        hop latency)."""
+        u_rows, consts, c_bytes = self._serial(lv)
         shape = t.shape
         k, u = shape[0], len(u_rows)
         x = np.concatenate([
@@ -385,13 +301,14 @@ class JaxScanEngine:
             state.free[u_rows].reshape(u, -1)])
         self.bytes_in += x.nbytes
         with span("scan.maxplus"), jax.enable_x64(True):
-            kernel = _rdv_level_kernel(float(r5_occ), float(rdma_startup))
+            kernel = _rdv_serial_kernel(float(r5_occ), float(rdma_startup))
             with span("scan.call"):
-                out = kernel(x, consts, forms)
-            self._record("rdv_level", static, out)
+                out = kernel(x, consts)
+            self._record("rdv_serial", consts["rows"].shape + (u,), out)
             with span("scan.fetch"):
                 out = np.asarray(out)
         self.bytes_out += out.nbytes
+        self.rdv_level_bytes += x.nbytes + c_bytes + out.nbytes
         state.free[u_rows] = out[k:].reshape((u,) + shape[1:])
         self.levels_fused += 1
         return out[:k].reshape(shape)

@@ -108,22 +108,38 @@ Op = Union[Compute, Isend, Irecv, Wait, Collective]
 
 @dataclasses.dataclass(frozen=True)
 class Program:
-    """One op sequence per rank (SPMD programs repeat the same shape)."""
+    """One op sequence per rank (SPMD programs repeat the same shape).
+
+    A Program is immutable (frozen, tuples of frozen ops), so what is
+    derived from it alone is computed once per object (:meth:`memo`):
+    a sweep that replays one program many times walks its ops once."""
     rank_ops: tuple[tuple[Op, ...], ...]
 
     @property
     def nranks(self) -> int:
         return len(self.rank_ops)
 
+    def memo(self, name: str, make):
+        """``make()``, computed on the first call for ``name`` and kept
+        on this object (outside the dataclass fields, so equality,
+        hashing and ``dataclasses.replace`` ignore it)."""
+        d = self.__dict__
+        if name not in d:
+            d[name] = make()
+        return d[name]
+
     def collectives(self) -> list[Collective]:
         """Unique Collective sites, in first-appearance order across ranks
         (what :meth:`CollectivePlanner.plan_program` plans in one pass)."""
+        return list(self.memo("_collectives", self._collectives))
+
+    def _collectives(self) -> tuple:
         seen: dict[tuple, Collective] = {}
         for ops in self.rank_ops:
             for op in ops:
                 if isinstance(op, Collective):
                     seen.setdefault((op.op, op.nbytes, op.algo), op)
-        return list(seen.values())
+        return tuple(seen.values())
 
     def compute_us(self, rank: int) -> float:
         """Total Compute microseconds of one rank (contention-free lower
@@ -150,6 +166,9 @@ class Program:
         (:mod:`repro.core.exanet.program_compiled`) lowered for one can be
         re-bound with the other's sizes — the Program analog of
         ``RoundProgram``'s per-(schedule, nranks) cache key."""
+        return self.memo("_structure_key", self._structure_key)
+
+    def _structure_key(self) -> tuple:
         sig = []
         for ops in self.rank_ops:
             row = []
@@ -309,6 +328,7 @@ class _Req:                       # requests even with identical fields
     is_send: bool
     t_post: float
     t_done: float | None = None
+    waiter: int | None = None   # the rank blocked on this request, if any
 
 
 class ProgramExecutor:
@@ -387,12 +407,22 @@ class ProgramExecutor:
         ready = [(t0s[r], r) for r in range(n) if prog.rank_ops[r]]
         heapq.heapify(ready)
 
-        def wake_waiters() -> None:
-            for r in [r for r, b in blocked.items() if b[0] == "wait"]:
-                reqs = blocked[r][1]
-                if all(q.t_done is not None for q in reqs):
-                    del blocked[r]
-                    clock[r] = max([clock[r]] + [q.t_done for q in reqs])
+        # blocked rank -> how many of the requests it waits on are open
+        pending: dict[int, int] = {}
+
+        def completed(reqs) -> None:
+            """Wake every rank whose Wait the just-completed ``reqs``
+            end (each rank once, when its last request completes)."""
+            for q in reqs:
+                r = q.waiter
+                if r is None:
+                    continue
+                q.waiter = None
+                pending[r] -= 1
+                if not pending[r]:
+                    del pending[r]
+                    waited = blocked.pop(r)[1]
+                    clock[r] = max([clock[r]] + [x.t_done for x in waited])
                     heapq.heappush(ready, (clock[r], r))
 
         while ready:
@@ -424,7 +454,7 @@ class ProgramExecutor:
                     other = q.popleft()
                     self._match(req if is_send else other,
                                 other if is_send else req)
-                    wake_waiters()
+                    completed((req, other))
                 else:
                     mine.setdefault(key, deque()).append(req)
             elif isinstance(op, Wait):
@@ -436,12 +466,20 @@ class ProgramExecutor:
                     except KeyError as e:
                         raise ProgramError(
                             f"rank {r}: Wait on unknown handle {e}") from e
-                if all(q.t_done is not None for q in reqs):
+                # by identity: a handle named twice is one request
+                open_reqs = list({id(q): q for q in reqs
+                                  if q.t_done is None}.values())
+                if not open_reqs:
                     clock[r] = max([clock[r]] + [q.t_done for q in reqs])
                 else:
                     blocked[r] = ("wait", list(reqs))
+                    pending[r] = len(open_reqs)
+                    for q in open_reqs:
+                        q.waiter = r
                 # consume: a waited request cannot be waited on again
-                outstanding[r] = [q for q in outstanding[r] if q not in reqs]
+                taken = {id(q) for q in reqs}
+                outstanding[r] = [q for q in outstanding[r]
+                                  if id(q) not in taken]
                 for q in reqs:
                     for h, v in list(named.items()):
                         if v is q:
@@ -478,9 +516,10 @@ class ProgramExecutor:
                                                  enters)
                         n_coll += 1
                         del barriers[site]
-                        for i, q in coll_reqs.pop(site).items():
+                        done = coll_reqs.pop(site)
+                        for i, q in done.items():
                             q.t_done = exits[i]
-                        wake_waiters()
+                        completed(done.values())
                 elif len(bar) == n:
                     enters = [bar[i] for i in range(n)]
                     exits = self._collective(op.op, op.nbytes, op.algo,

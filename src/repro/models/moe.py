@@ -13,7 +13,8 @@ Axis layout (matches tokens being DP-sharded over ``data``):
   psum over `model` combines the partial w_out contraction at the end.
 
 Two-level capacity buffers keep every shape static:
-1. route: top-k over a replicated router;
+1. route: top-k over a replicated router (:func:`route`, node-limited
+   where the config groups its experts);
 2. pack per-destination-shard capacity buffers (scatter by running index);
 3. ``all_to_all`` tokens + metadata to expert shards;
 4. pack again into per-local-expert buffers; batched expert GEMMs
@@ -93,6 +94,32 @@ def _qa2a_bwd(axis, _, g):
 _qa2a.defvjp(_qa2a_fwd, _qa2a_bwd)
 
 
+def route(logits, bias, m) -> jnp.ndarray:
+    """Expert ids ``(T, top_k)`` a router picks from ``logits`` ``(T, E)``
+    under the :class:`~repro.config.MoEConfig` ``m``.
+
+    Softmax routers select on the logits (the softmax keeps their order).
+    Sigmoid routers follow DeepSeek-V3's ``noaux_tc``: the scores
+    ``s = sigmoid(logits)`` plus the per-expert ``bias`` (``None``: zero)
+    rank the experts; with ``n_group > 1`` the experts fall into
+    ``n_group`` contiguous groups, a group's score is the sum of its two
+    best, and only the ``topk_group`` best groups stay eligible.  The
+    ``top_k`` best eligible experts are returned, best first.  The bias
+    only selects: combine weights come from the scores alone."""
+    scores = logits if m.router_softmax else jax.nn.sigmoid(logits)
+    sel = scores if bias is None else scores + bias
+    if m.n_group > 1:
+        T, E = sel.shape
+        size = E // m.n_group
+        grouped = sel.reshape(T, m.n_group, size)
+        gscore = jnp.sum(jax.lax.top_k(grouped, min(2, size))[0], axis=-1)
+        _, gids = jax.lax.top_k(gscore, m.topk_group)
+        keep = jnp.zeros((T, m.n_group), bool).at[
+            jnp.arange(T)[:, None], gids].set(True)
+        sel = jnp.where(jnp.repeat(keep, size, axis=1), sel, -jnp.inf)
+    return jax.lax.top_k(sel, m.top_k)[1]
+
+
 def _pack(dest, n_dest, capacity, payload):
     """Scatter ``payload`` rows into (n_dest, capacity, ...) buffers by
     running index within each destination. Returns (buffers, pos, valid)."""
@@ -127,12 +154,14 @@ def _moe_body(x, router_w, w_gate, w_up, w_out, cfg: ArchConfig,
     k = m.top_k
 
     logits = (x @ router_w.astype(x.dtype)).astype(jnp.float32)  # (T, E)
-    vals, ids = jax.lax.top_k(logits, k)                         # (T, k)
+    ids = route(logits, None, m)                                 # (T, k)
+    vals = jnp.take_along_axis(logits, ids, axis=-1)
     if m.router_softmax:
         w = jax.nn.softmax(vals, axis=-1)
     else:
         w = jax.nn.sigmoid(vals)
         w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    w = w * m.routed_scaling_factor
 
     flat_ids = ids.reshape(-1)                                   # (T*k,)
     flat_src = jnp.repeat(jnp.arange(T), k)

@@ -118,14 +118,23 @@ def lm_serve_step_cost(cfg, *, n_decode: float, decode_kv: float,
     :func:`model_flops_per_step` plus the KV-length-dependent attention
     term that rule omits; HBM bytes charge one weight sweep per step
     (shared by every token in the batch — the continuous-batching
-    economy) plus KV reads/writes.  Returned collective payloads are
+    economy) plus KV reads/writes (an MLA config caches its compressed
+    latent and rope key).  Returned collective payloads are
     whole-model totals; tensor-parallel sharding (the /nranks) is the
     caller's concern (:mod:`repro.serve.sim`).
     """
     P = float(cfg.param_count())
     L, hd = cfg.n_layers, cfg.resolved_head_dim
-    kv_tok = L * 2.0 * cfg.n_kv_heads * hd * dtype_bytes  # bytes/token
-    attn_fl_tok = 4.0 * L * cfg.n_heads * hd              # flops/token/ctx
+    if cfg.mla is not None:
+        # MLA caches one latent and one rope key per token and layer; the
+        # absorbed decode scores against both and sums the latent
+        ml = cfg.mla
+        kv_tok = L * (ml.kv_lora_rank + ml.qk_rope_head_dim) * dtype_bytes
+        attn_fl_tok = 2.0 * L * cfg.n_heads * (2 * ml.kv_lora_rank
+                                               + ml.qk_rope_head_dim)
+    else:
+        kv_tok = L * 2.0 * cfg.n_kv_heads * hd * dtype_bytes  # bytes/token
+        attn_fl_tok = 4.0 * L * cfg.n_heads * hd          # flops/token/ctx
     nd, npf = float(n_decode), float(n_prefill)
     tokens = nd + npf
     pf_ctx = prefill_kv + npf / 2.0

@@ -19,7 +19,9 @@ Names in use (the layer each belongs to, PERF.md §3):
   ``transport.waits``, ``transport.collective``,
   ``transport.link_consts``;
 * scan engine: ``scan.maxplus``, ``scan.running_max``, ``scan.call``,
-  ``scan.fetch``.
+  ``scan.fetch``;
+* expert-parallel decode emission (``serve/sim.py``): ``serve.ep_route``,
+  ``serve.ep_emit``.
 """
 
 from __future__ import annotations

@@ -38,6 +38,16 @@ run over thousands of ranks for a few migrated shards — and which would
 also dominate the compiled replay itself.  Both ops resolve to a single
 schedule regardless of payload, so per-column ``site_scale`` bindings
 can never flip the probe tape (the hazard ``algo="auto"`` sites have).
+
+Expert-parallel decode
+----------------------
+:class:`EPDecodeSim` emits the other layout: one decode step of a
+node-limited MoE model (DeepSeek-V3) with the routed experts spread over
+the ranks and attention, the shared expert and the router data-parallel,
+one rank per MPSoC.  The dispatch and combine are ``Isend``/``Irecv``
+all-to-alls whose message sizes follow the routing (an alltoallv, which
+no uniform collective schedule expresses); see
+:meth:`EPDecodeSim.emit_step` for the step and its message order.
 """
 
 from __future__ import annotations
@@ -46,7 +56,9 @@ import dataclasses
 
 import numpy as np
 
-from repro.core.program import Collective, Compute, Program
+from repro.core.program import (Collective, Compute, Irecv, Isend, Program,
+                                Wait)
+from repro.runtime import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,3 +281,196 @@ class ServeSim:
         prog = rebind_program(base, compute_us=comp, site_nbytes=site)
         return self.mpi.run_program(prog, backend=backend, engine=engine,
                                     t0=table.t0[:, b]).latency_us
+
+
+# ---------------------------------------------------------------------------
+# expert-parallel decode
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class EPDecodeSpec:
+    """One expert-parallel decode deployment: the routed experts split
+    evenly over ``nranks`` ranks (one per MPSoC), everything else
+    data-parallel with ``tokens_per_rank`` decoding sequences per rank at
+    ``context`` cached tokens each.  The step runs ``n_dense_layers``
+    dense layers, then ``n_moe_layers`` MoE layers."""
+    arch: str = "deepseek-v3-671b"
+    nranks: int = 128
+    tokens_per_rank: int = 16
+    context: int = 4096
+    n_dense_layers: int = 1
+    n_moe_layers: int = 4
+    #: weight and KV-cache bytes per element in the compute costs (FP8)
+    dtype_bytes: int = 1
+    #: dispatch payload per token: FP8 activations plus one float32
+    #: scale per ``dispatch_scale_block`` elements
+    dispatch_dtype_bytes: int = 1
+    dispatch_scale_block: int = 128
+    dispatch_scale_bytes: int = 4
+    #: combine payload per token: BF16 expert outputs
+    combine_dtype_bytes: int = 2
+    #: the per-core A53 roofline of :class:`ServeSimSpec`, times the
+    #: cores a rank owns
+    core_rate_flops_per_us: float = ServeSimSpec.core_rate_flops_per_us
+    mem_bw_bytes_per_us: float = ServeSimSpec.mem_bw_bytes_per_us
+    cores_per_rank: int = 4
+
+
+class EPDecodeSim:
+    """Emit one expert-parallel decode step of an :class:`EPDecodeSpec` as
+    a :class:`~repro.core.program.Program`, from router logits.
+
+    Expert ``e`` lives on rank ``e // (n_experts // nranks)``, so the
+    config's ``n_group`` routing groups are contiguous blocks of ranks
+    (on the ExaNeSt rack with 128 ranks: one group per blade) and
+    ``topk_group`` bounds the blocks a token's dispatch reaches.  Compute
+    times come from :func:`repro.roofline.analysis.lm_serve_step_cost`
+    over one-layer slices of the config, at the rank's roofline."""
+
+    def __init__(self, spec: EPDecodeSpec, cfg=None):
+        from repro.configs import get
+        self.spec = spec
+        self.cfg = cfg if cfg is not None else get(spec.arch)
+        m = self.cfg.moe
+        if m is None or m.n_experts % spec.nranks:
+            raise ValueError(f"{self.cfg.name}: the routed experts do not "
+                             f"split evenly over {spec.nranks} ranks")
+        self.experts_per_rank = m.n_experts // spec.nranks
+        d = self.cfg.d_model
+        self.dispatch_bytes = d * spec.dispatch_dtype_bytes + \
+            -(-d // spec.dispatch_scale_block) * spec.dispatch_scale_bytes
+        self.combine_bytes = d * spec.combine_dtype_bytes
+
+    # ------------------------------------------------------------- costing
+    def _slice(self, **kw):
+        return dataclasses.replace(self.cfg, n_layers=1, n_dense_layers=0,
+                                   vocab_size=0, mtp_depth=0, **kw)
+
+    def _us(self, cfg, n_tokens: float, context: float) -> float:
+        """One slice's roofline time for ``n_tokens`` decode tokens on
+        one rank."""
+        from repro.roofline.analysis import lm_serve_step_cost
+        sp = self.spec
+        c = lm_serve_step_cost(cfg, n_decode=n_tokens, decode_kv=context,
+                               dtype_bytes=sp.dtype_bytes)
+        return max(
+            c["flops"] / (sp.core_rate_flops_per_us * sp.cores_per_rank),
+            c["hbm_bytes"] / (sp.mem_bw_bytes_per_us * sp.cores_per_rank))
+
+    def dense_us(self) -> float:
+        """A dense layer (attention and dense FFN) on one rank's tokens."""
+        sp = self.spec
+        return self._us(self._slice(family="dense", moe=None),
+                        sp.tokens_per_rank, sp.context)
+
+    def data_parallel_us(self) -> float:
+        """An MoE layer's data-parallel part on one rank's tokens:
+        attention, shared expert and router (the routed experts left
+        out)."""
+        sp = self.spec
+        moe = dataclasses.replace(self.cfg.moe, d_expert=0)
+        return self._us(self._slice(moe=moe), sp.tokens_per_rank,
+                        sp.context)
+
+    def expert_us(self, n_tokens: int) -> float:
+        """One routed expert over ``n_tokens`` tokens (no work, no weight
+        read, for none)."""
+        if n_tokens <= 0:
+            return 0.0
+        cfg = self._slice(family="dense", moe=None, mla=None, n_heads=0,
+                          n_kv_heads=0, d_ff=self.cfg.moe.d_expert)
+        return self._us(cfg, n_tokens, 0.0)
+
+    # ------------------------------------------------------------- routing
+    def route(self, logits) -> np.ndarray:
+        """Expert ids ``(layers, tokens, top_k)`` for router logits
+        ``(layers, tokens, n_experts)`` (token ``t`` of rank ``r`` is row
+        ``r * tokens_per_rank + t``), by the model's own router
+        (:func:`repro.models.moe.route`), in float64 on the host."""
+        import jax
+        import jax.numpy as jnp
+        from repro.models.moe import route
+        logits = np.asarray(logits, dtype=np.float64)
+        sp = self.spec
+        want = (sp.n_moe_layers, sp.nranks * sp.tokens_per_rank,
+                self.cfg.moe.n_experts)
+        if logits.shape != want:
+            raise ValueError(f"logits must have shape {want}, got "
+                             f"{logits.shape}")
+        with spans.span("serve.ep_route"), jax.enable_x64(True), \
+                jax.default_device(jax.devices("cpu")[0]):
+            return np.stack([np.asarray(route(jnp.asarray(lg), None,
+                                              self.cfg.moe))
+                             for lg in logits])
+
+    def layer_traffic(self, ids) -> tuple:
+        """``(tokens, load)`` of one MoE layer from its expert ids
+        ``(tokens, top_k)``: ``tokens[s, d]`` counts rank ``s``'s tokens
+        that pick an expert on rank ``d != s`` (a token that picks two
+        experts on one rank goes there once; one on its own rank moves
+        nothing), ``load[e]`` the tokens expert ``e`` computes."""
+        sp = self.spec
+        ids = np.asarray(ids)
+        n_tok = ids.shape[0]
+        hit = np.zeros((n_tok, sp.nranks), dtype=bool)
+        hit[np.arange(n_tok)[:, None], ids // self.experts_per_rank] = True
+        src = np.arange(n_tok) // sp.tokens_per_rank
+        tokens = np.zeros((sp.nranks, sp.nranks), dtype=np.int64)
+        np.add.at(tokens, src, hit.astype(np.int64))
+        np.fill_diagonal(tokens, 0)
+        load = np.bincount(ids.ravel(), minlength=self.cfg.moe.n_experts)
+        return tokens, load
+
+    def rank_expert_us(self, load) -> np.ndarray:
+        """Each rank's routed-expert compute for the per-expert ``load``."""
+        per = np.array([self.expert_us(int(n)) for n in load])
+        return per.reshape(self.spec.nranks, self.experts_per_rank).sum(1)
+
+    # ------------------------------------------------------------ emission
+    def emit_step(self, logits) -> Program:
+        """One decode step as a Program, from router logits ``(layers,
+        tokens, n_experts)`` (see :meth:`route`).  Per rank ``r``::
+
+            Compute(dense layer) x n_dense_layers
+            for each MoE layer l:
+                Compute(attention + shared expert + router)
+                Irecv(s, n[s, r] * dispatch_bytes, tag 2l)  s = r-1, r-2, ...
+                Isend(d, n[r, d] * dispatch_bytes, tag 2l)  d = r+1, r+2, ...
+                Wait()
+                Compute(r's experts over the tokens they received)
+                Irecv(d, n[r, d] * combine_bytes, tag 2l+1)  d = r+1, ...
+                Isend(s, n[s, r] * combine_bytes, tag 2l+1)  s = r-1, ...
+                Wait()
+
+        with ``n = layer_traffic(...)[0]``; pairs that exchange no token
+        post nothing, ranks run modulo ``nranks``.  Messages are matched
+        and fire in the order of the interpreter's scheduler
+        (:class:`~repro.core.program.ProgramExecutor`: one op at a time,
+        the rank with the smallest clock first, the lower rank on a tie;
+        a transfer fires when its second side is posted), on the healthy
+        machine; scenario columns replay that order."""
+        ids = self.route(logits)
+        with spans.span("serve.ep_emit"):
+            sp = self.spec
+            n = sp.nranks
+            db, cb = self.dispatch_bytes, self.combine_bytes
+            head = (Compute(self.dense_us()),) * sp.n_dense_layers
+            dp = Compute(self.data_parallel_us())
+            ranks = [list(head) for _ in range(n)]
+            for layer, lid in enumerate(ids):
+                tok, load = self.layer_traffic(lid)
+                eus = self.rank_expert_us(load)
+                td, tc = 2 * layer, 2 * layer + 1
+                for r, ops in enumerate(ranks):
+                    srcs = [s for s in ((r - i) % n for i in range(1, n))
+                            if tok[s, r]]
+                    dsts = [d for d in ((r + i) % n for i in range(1, n))
+                            if tok[r, d]]
+                    ops.append(dp)
+                    ops += [Irecv(s, int(tok[s, r]) * db, td) for s in srcs]
+                    ops += [Isend(d, int(tok[r, d]) * db, td) for d in dsts]
+                    ops.append(Wait())
+                    ops.append(Compute(float(eus[r])))
+                    ops += [Irecv(d, int(tok[r, d]) * cb, tc) for d in dsts]
+                    ops += [Isend(s, int(tok[s, r]) * cb, tc) for s in srcs]
+                    ops.append(Wait())
+            return Program(tuple(tuple(ops) for ops in ranks))

@@ -214,6 +214,81 @@ def test_batched_link_axes_jax_engine_agrees():
         assert _rel(x, y) <= RTOL
 
 
+def _dense_link_consts(deg, lv) -> dict:
+    """Every level constant recomputed for every (send, column), the
+    formulas written out once more: what the degraded-pair update of
+    ``LinkDegrade`` has to reproduce."""
+    mask = lv.link_ids >= 0
+    idx = np.where(mask, lv.link_ids, 0)
+    s = deg.slow[idx]
+    exsum = np.where(mask[..., None], deg.extra[idx], 0.0).sum(axis=1)
+    rate = np.where(mask[..., None], lv.link_rate[..., None], np.inf)
+    has = (lv.n_links > 0)[:, None]
+    wire = np.where(mask[..., None], lv.link_wire[..., None] / s, np.inf)
+    t_block = deg._block_bits / (wire.min(axis=1) * 1000.0) + deg._gap_us
+    bw = deg._block_bits / t_block / 1000.0
+    return {"e_const": lv.e_const + exsum,
+            "eager_pb": np.where(has, (8.0 / ((rate / s) * 1000.0)).sum(
+                axis=1), lv.eager_pb),
+            "handshake": lv.handshake + 2.0 * exsum,
+            "stream_pb": np.where(has, 8.0 / (bw * 1000.0), lv.stream_pb),
+            "hop": lv.hop + exsum}
+
+
+def test_link_constants_recompute_only_the_degraded_pairs(monkeypatch):
+    """The per-column link constants equal the formulas over every (send,
+    column) pair, bit for bit, while only the pairs a degraded link
+    touches are recomputed."""
+    from repro.core.exanet import exec_compiled as ec
+    seen = []
+    derive = ec.LinkDegrade._derive
+
+    def spy(self, lv, sel, s, ex):
+        seen.append((self, lv, s.shape[0]))
+        return derive(self, lv, sel, s, ex)
+
+    monkeypatch.setattr(ec.LinkDegrade, "_derive", spy)
+    base = ExanetMPI()
+    rng = np.random.default_rng(11)
+    specs = [sample_fault_spec(rng, base.topo, n_slow_links=2,
+                               n_lossy_links=1, extra_latency_us=4.0)
+             for _ in range(6)] + [HEALTHY]
+    prog = halo3d(64, 65536, compute_us=40.0)
+    base.run_program_scenarios(prog, **batch_fault_axes(specs, prog))
+    levels = {id(lv): (deg, lv) for deg, lv, _ in seen}
+    assert levels
+    for deg, lv in levels.values():
+        got, want = deg.consts(lv), _dense_link_consts(deg, lv)
+        for name in want:
+            np.testing.assert_array_equal(got[name], want[name], name)
+        # the healthy column keeps the level's own constants
+        np.testing.assert_array_equal(got["handshake"][:, -1:],
+                                      lv.handshake)
+    k, n = next(iter(levels.values()))[1].link_ids.shape[0], len(specs)
+    redone = [m for deg, lv, m in seen if m != lv.link_ids.shape[0]]
+    assert redone and max(redone) < k * n
+
+
+def test_a_link_only_sweep_lays_its_payload_out_once():
+    """Entry clocks and link faults leave the payload the same in every
+    column: it binds once and every column reads it, with the results of
+    a sweep that binds the same payload column by column."""
+    base = ExanetMPI()
+    rng = np.random.default_rng(13)
+    specs = [sample_fault_spec(rng, base.topo, n_slow_links=2)
+             for _ in range(3)]
+    prog = halo3d(16, 32768, compute_us=25.0)
+    axes = batch_fault_axes(specs, prog)
+    axes.pop("compute_scale", None)
+    once = base.run_program_scenarios(prog, **axes)
+    by_column = base.run_program_scenarios(prog, **axes,
+                                           compute_scale=np.ones(3))
+    assert once == by_column
+    bound = base.program_artifact(prog).bind_arrays(prog, ncols=3)
+    assert bound.B == 3 and bound.post_off.shape[1] == 3
+    assert bound.post_off.strides[1] == 0 and bound.seg_total.strides[1] == 0
+
+
 def test_batch_fault_axes_validation():
     with pytest.raises(ValueError, match="structural"):
         batch_fault_axes([FaultSpec(dead_links=[("mezz", 0, 4)])])

@@ -344,3 +344,20 @@ def test_app_iteration_programs_have_halo_and_dots():
     assert c["collective"] == 8 * 2        # 2 dot allreduces per rank
     assert all(col.algo == "recursive_doubling"   # MPICH 3.2.1, §5.2.1
                for col in prog.collectives())
+
+
+def test_what_a_program_derives_is_walked_once():
+    """Derived data is kept on the (immutable) program object: computed
+    once, ignored by equality and hashing, and not carried by
+    ``dataclasses.replace``."""
+    from repro.core.exanet.program_compiled import extract_data
+    prog = halo3d(8, 4096, compute_us=10.0)
+    key = prog.structure_key()
+    assert prog.structure_key() is key
+    assert extract_data(prog) is extract_data(prog)
+    assert prog.collectives() == prog.collectives() == []
+    twin = halo3d(8, 4096, compute_us=10.0)
+    assert twin == prog and hash(twin) == hash(prog)
+    moved = dataclasses.replace(prog)
+    assert "_structure_key" not in moved.__dict__
+    assert moved.structure_key() == key

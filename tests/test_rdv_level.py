@@ -1,8 +1,8 @@
-"""A rendez-vous level's acquire chain as one jax dispatch
-(``JaxScanEngine.rdv_level``, DESIGN.md §2.5): it agrees with the staged
-chain of per-stage kernels, it engages on every unmasked level of the
-512-rank HPCG replay, and it moves only what its operands and outputs
-hold."""
+"""A compiled program's rendez-vous level as one jax dispatch
+(``JaxScanEngine.rdv_serial``, DESIGN.md §2.5): it agrees with the staged
+chain of per-stage kernels, the 512-rank HPCG replay runs as one such
+level, and it moves only what its operands and outputs hold; collective
+rounds keep the staged chain."""
 
 import gc
 
@@ -43,11 +43,6 @@ def _collective(sizes, eng):
     return np.column_stack([r.latency_us, r.clocks])
 
 
-def _forms(eng) -> set:
-    return {int(f) for *_, forms in eng._level_cache.values()
-            for fs in forms.values() for f in np.asarray(fs)}
-
-
 @pytest.mark.parametrize("case", ["hpcg512_link_faults",
                                   "collective_rdv_uniform",
                                   "collective_mixed_round"])
@@ -59,9 +54,9 @@ def test_fused_level_agrees_with_the_staged_chain(case, request,
         def run(eng):
             return _hpcg512_replay(hpcg512, eng)
     else:
-        # 64 KiB and 1 MiB are both rendez-vous: every level runs over
-        # all columns, undegraded, so the running-max forms apply; a grid
-        # that also holds an eager size (8 B) splits each round's columns
+        # 64 KiB and 1 MiB are both rendez-vous, over all columns and
+        # undegraded; a grid that also holds an eager size (8 B) splits
+        # each round's columns.  Collective rounds run staged either way
         sizes = (65536, 1 << 20) if case == "collective_rdv_uniform" \
             else (8, 65536)
 
@@ -70,14 +65,11 @@ def test_fused_level_agrees_with_the_staged_chain(case, request,
     fused, staged = se.JaxScanEngine(), staged_jax_engine
     a, b = run(fused), run(staged)
     assert staged.levels_fused == 0 and staged.levels_staged > 0
-    if case == "collective_mixed_round":
+    if case == "hpcg512_link_faults":
+        assert fused.levels_fused == 1 and fused.levels_staged == 0
+    else:
         assert fused.levels_fused == 0
         assert fused.levels_staged == staged.levels_staged
-    else:
-        assert fused.levels_fused == staged.levels_staged
-        assert fused.levels_staged == 0
-    if case == "collective_rdv_uniform":
-        assert _forms(fused) == {se._RUNNING_MAX}
     np.testing.assert_allclose(a, b, rtol=1e-12, atol=0)
     np.testing.assert_allclose(a, run(None), rtol=1e-12, atol=0)
 
@@ -99,10 +91,11 @@ def _counting_running_max(monkeypatch, seen):
 
 def test_hpcg512_replay_runs_each_level_as_one_dispatch(hpcg512,
                                                         monkeypatch):
-    """24 rendez-vous levels and 18 eager running maxima per sweep; a
-    level sends its stacked issue times, stream durations and row free
-    times and gets back stream ends and free times, while its constants
-    crossed once, on the first sweep."""
+    """One serial rendez-vous level (the iteration's 3,072 halo sends)
+    and 18 eager running maxima per sweep; the level sends its stacked
+    issue times, stream durations and row free times and gets back stream
+    ends and free times, while its row table crossed once, on the first
+    sweep."""
     seen = {"in": 0, "out": 0}
     _counting_running_max(monkeypatch, seen)
     eng = se.JaxScanEngine()
@@ -112,20 +105,20 @@ def test_hpcg512_replay_runs_each_level_as_one_dispatch(hpcg512,
         _hpcg512_replay(hpcg512, eng)
         moved.append((eng.bytes_in - before[0] - (seen["in"] - before[2]),
                       eng.bytes_out - before[1] - (seen["out"] - before[3])))
-    assert eng.levels_fused == 2 * 24 and eng.levels_staged == 0
-    assert sum(eng.dispatches.values()) == 2 * 42
+    assert eng.levels_fused == 2 * 1 and eng.levels_staged == 0
+    assert sum(eng.dispatches.values()) == 2 * 19
     assert sum(n for (kernel, *_), n in eng.dispatches.items()
-               if kernel == "rdv_level") == 2 * 24
-    assert len(eng._level_cache) == 24
+               if kernel == "rdv_serial") == 2 * 1
+    assert len(eng._level_cache) == 1
 
     B = 16
     ops_in = ops_out = consts = 0
-    for lv, (u_rows, dev, _, forms) in eng._level_cache.items():
+    for lv, (u_rows, dev, _) in eng._level_cache.items():
         k, u = len(lv.sel), len(u_rows)
+        assert k == 3072
         ops_in += (2 * k + u) * B * 8
         ops_out += (k + u) * B * 8
-        consts += sum(a.nbytes for a in
-                      jax.tree_util.tree_leaves((dev, forms)))
+        consts += sum(a.nbytes for a in jax.tree_util.tree_leaves(dev))
     assert consts > 0
     assert moved[1] == (ops_in, ops_out)
     assert moved[0] == (ops_in + consts, ops_out)
@@ -135,8 +128,8 @@ def test_a_level_s_device_constants_go_with_the_level():
     """Programs compiled per call (here, those of an ExanetMPI that is
     dropped) leave no level constants on the device behind."""
     eng, mpi = se.JaxScanEngine(), ExanetMPI()
-    mpi.run_schedule_many(RecursiveDoublingAllreduce(), (65536, 1 << 20),
-                          16, engine=eng)
+    mpi.run_program_scenarios(ALL_APPS["hpcg"]().emit_iteration("weak", 16),
+                              compute_scale=np.ones(2), engine=eng)
     assert eng.levels_fused > 0 and len(eng._level_cache) > 0
     del mpi
     gc.collect()

@@ -128,19 +128,19 @@ def test_jax_engine_counts_the_bytes_each_kernel_call_moves(
         monkeypatch, staged_jax_engine):
     """Both the fused levels and the staged chain's per-stage kernels."""
     seen = {}
-    for kind in ("maxplus", "running_max", "rdv_level"):
+    for kind in ("maxplus", "running_max", "rdv_serial"):
         name = "_maxplus_kernel" if kind == "maxplus" else f"_{kind}_kernel"
         monkeypatch.setattr(se, name,
                             _counting(getattr(se, name), seen, kind))
-    for eng, kernels in ((se.JaxScanEngine(), ("rdv_level", "running_max")),
+    for eng, kernels in ((se.JaxScanEngine(), ("rdv_serial", "running_max")),
                          (staged_jax_engine, ("maxplus", "running_max"))):
         seen.update({"in": 0, "out": 0, "maxplus": 0, "running_max": 0,
-                     "rdv_level": 0, "on_device": set()})
+                     "rdv_serial": 0, "on_device": set()})
         _replay(eng)
-        assert {k for k in ("maxplus", "running_max", "rdv_level")
+        assert {k for k in ("maxplus", "running_max", "rdv_serial")
                 if seen[k]} == set(kernels)
         assert sum(eng.dispatches.values()) == \
-            seen["maxplus"] + seen["running_max"] + seen["rdv_level"]
+            seen["maxplus"] + seen["running_max"] + seen["rdv_serial"]
         assert eng.bytes_in == seen["in"] > 0
         assert eng.bytes_out == seen["out"] > 0
 

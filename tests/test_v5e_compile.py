@@ -4,7 +4,8 @@ Nothing runs: each case lowers a jitted function against shapes placed on
 one chip of a *described* ``v5e:2x2`` topology and has the TPU compiler
 build it, which refuses what the chip would refuse (unaligned tiles, too
 much fast memory, a program that does not fit).  Cases: the replay's
-float64 scan kernels at a 512-rank stage shape, exanest-lm-100m's
+float64 scan kernels at a 512-rank stage shape, the serial rendez-vous
+level of the DeepSeek-V3 expert-parallel replay, exanest-lm-100m's
 ``decode_step`` at the serve shape, and the Pallas kernels that lower for
 the TPU (``ssd_scan`` does not: Mosaic has no ``cumsum``, and its ``dt``
 block breaks the (8, 128) tiling for a head block below the head count).
@@ -81,6 +82,27 @@ def test_scan_kernel_compiles_in_float64(one_chip, kernel):
     outs = jax.tree_util.tree_leaves(compiled.out_info)
     assert outs and all(o.dtype == jnp.float64 and o.shape == (k, cols)
                         for o in outs)
+
+
+#: the largest serial level of the DeepSeek-V3 expert-parallel decode
+#: replay (sends, stages, rows, columns)
+SERIAL_LEVEL = (9271, 10, 721, 64)
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+def test_serial_rdv_level_compiles(one_chip, dtype):
+    k, n_stages, u, cols = SERIAL_LEVEL
+    consts = {"rows": jax.ShapeDtypeStruct((k, n_stages), jnp.int32,
+                                           sharding=one_chip),
+              "valid": jax.ShapeDtypeStruct((k, n_stages), jnp.bool_,
+                                            sharding=one_chip)}
+    with jax.enable_x64(dtype == "float64"):
+        x = jax.ShapeDtypeStruct((2 * k + u, cols), jnp.dtype(dtype),
+                                 sharding=one_chip)
+        compiled = scan_engine._rdv_serial_kernel(1.4, 2.4).lower(
+            x, consts).compile()
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (k + u, cols) and out.dtype == jnp.dtype(dtype)
 
 
 def test_lm_decode_step_compiles(one_chip):
